@@ -27,12 +27,12 @@ import json
 import os
 
 import numpy as np
-import jax
 
 from repro.core import compress
 from repro.core.partition import PartitionedQuery, PartitionedTable
 from repro.core.plan import col
-from benchmarks.common import ART_DIR, count_h2d, time_interleaved
+from benchmarks.common import (ART_DIR, count_h2d, device_info,
+                               time_interleaved)
 
 DICT_CARD = 500  # 9-bit dictionary code space per string column
 
@@ -102,7 +102,7 @@ def run(n=2_000_000, num_partitions=16, out_name="BENCH_compress.json"):
                  / max(results["packed"]["h2d_bytes"], 1))
     report = {
         "bench": "compress_bitpack",
-        "backend": jax.default_backend(),
+        **device_info(),
         "rows": n,
         "num_partitions": num_partitions,
         "dict_cardinality": DICT_CARD,

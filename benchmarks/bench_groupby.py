@@ -22,7 +22,7 @@ from repro.core import groupby as G
 from repro.core.plan import Query, col
 from repro.core.table import Table
 from repro.kernels import dispatch
-from benchmarks.common import ART_DIR, time_fn
+from benchmarks.common import ART_DIR, device_info, time_fn
 
 N_KEYS = 1000  # dictionary cardinality
 NUM_GROUPS_CAP = 1024
@@ -91,7 +91,7 @@ def run(n=10_000_000, out_name="BENCH_groupby.json"):
 
     report = {
         "bench": "groupby_sortfree",
-        "backend": jax.default_backend(),
+        **device_info(),
         "rows": n,
         "dict_cardinality": N_KEYS,
         "num_groups_cap": NUM_GROUPS_CAP,

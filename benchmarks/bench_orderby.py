@@ -20,7 +20,6 @@ import json
 import os
 
 import numpy as np
-import jax
 
 from repro.core import compress
 from repro.core import partition as P
@@ -28,7 +27,7 @@ from repro.core.partition import PartitionedQuery, PartitionedTable
 from repro.core.plan import Query
 from repro.core.table import Table
 from repro.kernels import dispatch
-from benchmarks.common import ART_DIR, rle_friendly, time_fn
+from benchmarks.common import ART_DIR, device_info, rle_friendly, time_fn
 
 N_KEYS = 1000  # dictionary cardinality of the order key
 LIMIT = 10
@@ -97,7 +96,7 @@ def run(n=10_000_000, out_name="BENCH_orderby.json"):
 
     report = {
         "bench": "orderby",
-        "backend": jax.default_backend(),
+        **device_info(),
         "rows": n,
         "dict_cardinality": N_KEYS,
         "limit": LIMIT,

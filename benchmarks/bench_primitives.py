@@ -1,9 +1,10 @@
 """Paper Fig. 3: primitive microbenchmarks across input sizes.
 
-The paper compares CPU vs GPU; on this container both run the CPU backend,
-so the reported axis is *scaling with input size* for the four fundamental
-primitives plus the conversion kernels. The crossover story of Fig. 3 (fixed
-launch overhead vs linear work) shows up as near-flat time below ~100K.
+The paper compares CPU vs GPU; this harness times one backend, the one JAX
+runs on (``write_csv`` names it), so the reported axis is *scaling with
+input size* for the four fundamental primitives plus the conversion
+kernels. The crossover story of Fig. 3 (fixed launch overhead vs linear
+work) shows up as near-flat time below ~100K.
 """
 from __future__ import annotations
 

@@ -43,7 +43,7 @@ from repro.core.partition import PartitionedQuery, PartitionedTable
 from repro.core.plan import col
 from repro.core.serve import QueryServer
 from repro.kernels import dispatch
-from benchmarks.common import ART_DIR
+from benchmarks.common import ART_DIR, device_info
 from benchmarks.bench_compress import make_dict_heavy
 
 
@@ -158,7 +158,7 @@ def run(n=2_000_000, num_partitions=16, repeats=4,
     nq = len(workload)
     out = {
         "bench": "serving",
-        "backend": jax.default_backend(),
+        **device_info(),
         "rows": n,
         "num_partitions": num_partitions,
         "workload_queries": nq,

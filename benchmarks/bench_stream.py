@@ -45,7 +45,8 @@ from repro.core.partition import PartitionedQuery, PartitionedTable
 from repro.core.plan import col
 from repro.kernels import dispatch
 from benchmarks.bench_compress import make_dict_heavy
-from benchmarks.common import ART_DIR, count_h2d, time_interleaved
+from benchmarks.common import (ART_DIR, count_h2d, device_info,
+                               time_interleaved)
 
 DEPTHS = (0, 1, 2, 4)
 DEFAULT_DEPTH = 2
@@ -158,7 +159,7 @@ def run(n=2_000_000, num_partitions=16, out_name="BENCH_stream.json"):
 
     report = {
         "bench": "stream_overlap",
-        "backend": jax.default_backend(),
+        **device_info(),
         "rows": n,
         "num_partitions": num_partitions,
         "compute_only_ms": round(lower_bound, 3),
@@ -267,7 +268,7 @@ def chaos(n=1_000_000, num_partitions=16, seed=11,
 
     report = {
         "bench": "fault_recovery",
-        "backend": jax.default_backend(),
+        **device_info(),
         "rows": n,
         "num_partitions": num_partitions,
         "seed": seed,

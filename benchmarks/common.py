@@ -1,10 +1,11 @@
 """Shared benchmark utilities: timing, CSV output, transfer counting,
 data generators.
 
-All benches run on the CPU backend at reduced row counts (DESIGN.md §9
-deviation 5): absolute times are not comparable to the paper's A100 numbers,
-but the *relative* Plain-vs-Compressed comparisons — which are the paper's
-claims — are preserved, and every harness mirrors one paper table/figure.
+These harnesses each mirror one paper table/figure at reduced row counts
+(DESIGN.md §9 deviation 5). They time whatever backend JAX runs on, and
+every result names it (``device_info``): a time taken on the CPU backend
+says how fast XLA:CPU or the Pallas interpreter is, never the TPU, and is
+not comparable to the paper's A100 numbers.
 """
 from __future__ import annotations
 
@@ -19,6 +20,14 @@ import jax
 
 ART_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                        "artifacts", "bench")
+
+
+def device_info() -> Dict[str, object]:
+    """What a result was measured on, recorded beside every number."""
+    dev = jax.devices()[0]
+    return {"backend": jax.default_backend(), "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": len(jax.devices())}
 
 
 def time_fn(fn: Callable, *args, warmup: int = 2, iters: int = 5) -> float:
@@ -94,7 +103,9 @@ def write_csv(name: str, rows: List[Dict], print_table: bool = True):
             print("  " + " | ".join(
                 f"{(f'{v:.4g}' if isinstance(v, float) else str(v)):>14s}"
                 for v in r.values()))
-    print(f"  -> {path}")
+    dev = device_info()
+    print(f"  -> {path} (measured on {dev['platform']} "
+          f"{dev['device_kind']} x{dev['device_count']})")
     return path
 
 

@@ -18,6 +18,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
     q = args.quick
 
+    from repro import compile_cache
+    print(f"compile cache: {compile_cache.configure()}")
+
     from benchmarks import (bench_and_design, bench_bi, bench_compress,
                             bench_compression_quality, bench_groupby,
                             bench_memory, bench_orderby, bench_outofcore,

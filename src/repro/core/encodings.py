@@ -380,17 +380,11 @@ def _run_id_per_row(starts, n, nrows: int) -> jax.Array:
 def decode_rle_values(col: RLEColumn, fill=0) -> jax.Array:
     """Expand RLE to a dense [nrows] value array (gaps -> fill).
 
-    Dispatch-routed (DESIGN.md §5): the fused Pallas ``rle_decode`` kernel
-    when the policy picks it (TPU / forced), else the XLA formulation
-    below — one cumsum total: coverage is derived from the run id
-    (row <= run end) instead of a second delta sweep; on the CPU backend
-    every 2M-row pass is ~4 ms, so pass count is the whole game."""
-    from repro.kernels import dispatch
+    One cumsum total: coverage is derived from the run id (row <= run end)
+    instead of a second delta sweep, so pass count stays at one. The
+    Pallas ``rle_decode`` kernels are off the route
+    (``dispatch.OFF_TPU_ROUTE``)."""
     starts, ends = unpack_values(col.starts), unpack_values(col.ends)
-    routed = dispatch.maybe_rle_decode(col.values, starts, ends,
-                                       col.n, col.nrows, fill)
-    if routed is not None:
-        return routed
     run_raw = _run_id_per_row(starts, col.n, col.nrows)
     run = jnp.clip(run_raw, 0, col.capacity - 1).astype(POS_DTYPE)
     rows = jnp.arange(col.nrows, dtype=POS_DTYPE)

@@ -490,18 +490,14 @@ def rle_to_index(values, rs, re, n, nrows: int, cap_out: int):
 
 
 def rle_to_plain(values, rs, re, n, nrows: int, fill=0):
-    """Expand RLE to a dense [nrows] array.
-
-    Dispatch-routed (DESIGN.md §5): the Pallas ``rle_decode`` kernel when
-    the policy picks it, otherwise the O(n) scatter+cumsum sweep (see
-    encodings._run_id_per_row for why not binary search per row)."""
+    """Expand RLE to a dense [nrows] array: the O(n) scatter+cumsum sweep
+    (see encodings._run_id_per_row for why not binary search per row; the
+    Pallas ``rle_decode`` kernel is off the route,
+    ``dispatch.OFF_TPU_ROUTE``)."""
     from repro.core.encodings import _run_id_per_row, decode_rle_coverage
     rs, re = unpack_values(rs), unpack_values(re)
     if values is None:
         return decode_rle_coverage(rs, re, n, nrows)
-    routed = dispatch.maybe_rle_decode(values, rs, re, n, nrows, fill)
-    if routed is not None:
-        return routed
     covered = decode_rle_coverage(rs, re, n, nrows)
     run = jnp.clip(_run_id_per_row(rs, n, nrows), 0, rs.shape[0] - 1)
     values = unpack_values(values)

@@ -5,18 +5,19 @@ of range_intersect (Alg. 1), idx_in_rle (Alg. 3), idx_in_idx (Alg. 4),
 rle_contain_idx (Alg. 5), run expansion and the sort-merge join probe. The
 paper leans on torch.bucketize; this is the TPU-native equivalent.
 
-Two variants (chosen by `ops.bucketize` based on boundary size):
+Two variants (chosen by `ops.bucketize` based on boundary size; only the
+second compiles for the TPU — ``_bsearch``'s per-lane 1-D gather has no
+Mosaic lowering, see ``dispatch.OFF_TPU_ROUTE``):
 
 1. ``bucketize_kernel`` — boundaries staged HBM->VMEM once per grid step
    (they fit VMEM up to ~2M int32 entries); each lane runs a branch-free
    log2(B)-step binary search (fori_loop with static trip count). Query
    tiles stream through the grid. Work O(Q log B), VMEM = B + Q_TILE.
 
-2. ``bucketize_count_kernel`` — for boundaries beyond VMEM: 2-D grid over
-   (query tiles × boundary tiles); each step adds the per-tile counts
-   #\\{j in tile : b[j] <= q\\} into the output block (sequential-grid
-   accumulation). Work O(Q·B / lanes) — only used when B is huge and the
-   comparison is one VPU op per element anyway.
+2. ``bucketize_count_kernel`` — 2-D grid over (query tiles × boundary
+   tiles); each step adds the per-tile counts #\\{j in tile : b[j] <= q\\}
+   into the output block (sequential-grid accumulation). Work
+   O(Q·B / lanes); dispatch routes it for boundary lists of one tile.
 
 Both compute counts (== searchsorted indices), matching ref.ref_bucketize.
 """
@@ -80,7 +81,7 @@ def bucketize_kernel(boundaries: jax.Array, queries: jax.Array, right: bool = Tr
     return out[:n_q]
 
 
-def _count_body(right: bool, b_ref, q_ref, o_ref):
+def _count_body(right: bool, n_b: int, b_ref, q_ref, o_ref):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -90,16 +91,14 @@ def _count_body(right: bool, b_ref, q_ref, o_ref):
     b = b_ref[...]
     q = q_ref[...]
     cmp = (b[None, :] <= q[:, None]) if right else (b[None, :] < q[:, None])
-    o_ref[...] += jnp.sum(cmp, axis=1).astype(jnp.int32)
+    # padding slots never count, even against a query equal to the pad value
+    real = j * B_TILE + jax.lax.iota(jnp.int32, B_TILE) < n_b
+    o_ref[...] += jnp.sum(cmp & real[None, :], axis=1).astype(jnp.int32)
 
 
 def bucketize_count_kernel(boundaries: jax.Array, queries: jax.Array,
                            right: bool = True, interpret: bool = False) -> jax.Array:
-    """Tiled-count variant for boundary lists beyond the VMEM budget.
-
-    Requires sentinel-padded boundaries: pad value must exceed every query
-    so padded slots contribute 0 to the count.
-    """
+    """Tiled-count variant: every query against every boundary tile."""
     n_b = boundaries.shape[0]
     n_q = queries.shape[0]
     q_pad = -(-n_q // Q_TILE) * Q_TILE
@@ -107,12 +106,10 @@ def bucketize_count_kernel(boundaries: jax.Array, queries: jax.Array,
     if q_pad != n_q:
         queries = jnp.pad(queries, (0, q_pad - n_q))
     if b_pad != n_b:
-        pad_val = (jnp.iinfo(boundaries.dtype).max
-                   if jnp.issubdtype(boundaries.dtype, jnp.integer) else jnp.inf)
-        boundaries = jnp.pad(boundaries, (0, b_pad - n_b), constant_values=pad_val)
+        boundaries = jnp.pad(boundaries, (0, b_pad - n_b))
     grid = (q_pad // Q_TILE, b_pad // B_TILE)
     out = pl.pallas_call(
-        functools.partial(_count_body, right),
+        functools.partial(_count_body, right, n_b),
         grid=grid,
         in_specs=[
             pl.BlockSpec((B_TILE,), lambda i, j: (j,)),

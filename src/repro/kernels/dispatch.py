@@ -1,8 +1,8 @@
 """Encoding-aware kernel dispatch policy (DESIGN.md §5).
 
-The query engine's three dominant primitives — ``bucketize`` (binary
-search, the core of every §4 range algorithm), ``rle_decode`` (run
-expansion) and ``segment_sum`` (group-by scatter-reduce) — each have a
+The query engine's hot primitives — ``unpack`` (sub-byte extraction),
+``bucketize`` (binary search, the core of every §4 range algorithm),
+``segment_sum`` (group-by scatter-reduce) and ``topk`` — each have a
 Pallas TPU kernel in this package and a pure-XLA formulation. This module
 is the single place that decides, AT TRACE TIME, which implementation a
 call site gets, so the decision composes with ``jax.jit`` (the routing is
@@ -14,7 +14,7 @@ Policy resolution, in order:
   1. an explicit ``overrides(...)`` / ``set_policy(...)`` (tests, benches),
   2. environment variables at import (``REPRO_USE_PALLAS`` = ``1``/``0``/
      ``auto``, ``REPRO_SORT_FREE``, ``REPRO_SORT_FREE_MAX_DOMAIN``,
-     ``REPRO_BUCKETIZE_MIN_QUERIES``, ``REPRO_RLE_DECODE_MIN_ROWS``,
+     ``REPRO_BUCKETIZE_MIN_QUERIES``,
      ``REPRO_SEGSUM_MAX_GROUPS``, ``REPRO_PACK``, ``REPRO_PACK_MAX_BITS``,
      ``REPRO_UNPACK_MIN_VALS``, ``REPRO_PREFETCH_DEPTH``,
      ``REPRO_SERVE_BUDGET_BYTES``, ``REPRO_PLAN_CACHE_SIZE``,
@@ -41,20 +41,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.bucketize import (
-    MAX_VMEM_BOUNDARIES,
-    bucketize_count_kernel,
-    bucketize_kernel,
-)
-from repro.kernels.rle_decode import rle_decode_kernel
+from repro.kernels.bucketize import B_TILE, bucketize_count_kernel
 from repro.kernels.segment_reduce import segment_sum_kernel
 from repro.kernels.topk import MAX_KERNEL_K, topk_kernel
-from repro.kernels.unpack import (
-    MAX_VMEM_WORDS,
-    bucketize_packed_kernel,
-    rle_decode_packed_kernel,
-    unpack_kernel,
-)
+from repro.kernels.unpack import unpack_kernel
 from repro.kernels import ref as ref_mod
 
 # dtypes the 1-D kernels handle natively (4-byte words; narrower dtypes
@@ -63,6 +53,24 @@ from repro.kernels import ref as ref_mod
 _KERNEL_DTYPES = (jnp.int32, jnp.float32)
 
 MAX_MATMUL_SEGMENTS = 4096  # one-hot matmul: G must fit a VMEM block
+# The counting bucketize compares every query with every boundary, so it
+# takes one boundary tile at most; longer lists keep XLA's searchsorted.
+COUNT_KERNEL_MAX_BOUNDARIES = B_TILE
+
+# Kernels dispatch never routes to, with the TPU compiler's reason. Each
+# rests on a per-lane gather from a VMEM-resident 1-D block (the binary
+# search's probe, the run-value fetch, the packed-word fetch), which
+# Mosaic refuses; XLA's searchsorted and run-expansion sweep run in their
+# place. They stay in the package, reachable through ``ops`` for
+# interpret-mode parity; ROADMAP A2 lists them for a rewrite that compiles
+# and a measured win on the chip.
+_NO_1D_GATHER = "Mosaic: NotImplementedError: Only 2D gather is supported"
+OFF_TPU_ROUTE = {
+    "bucketize_kernel": _NO_1D_GATHER,
+    "rle_decode_kernel": _NO_1D_GATHER,
+    "bucketize_packed_kernel": _NO_1D_GATHER,
+    "rle_decode_packed_kernel": _NO_1D_GATHER,
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,13 +78,12 @@ class DispatchPolicy:
     """Backend + size-threshold routing policy. All fields host-static."""
 
     use_pallas: Optional[bool] = None  # None = auto: TPU backends only
-    interpret: Optional[bool] = None  # None = auto: interpret off-TPU
+    # None = auto: interpret off the TPU, compiled on it. Deliberately no
+    # environment variable: interpret mode on the chip is set in code only.
+    interpret: Optional[bool] = None
     # bucketize: below this many queries the XLA searchsorted is cheaper
     # than staging boundaries into VMEM.
     bucketize_min_queries: int = 4096
-    bucketize_max_vmem_boundaries: int = MAX_VMEM_BOUNDARIES
-    # rle_decode: tiny columns are latency-bound; keep the fused XLA sweep.
-    rle_decode_min_rows: int = 4096
     # segment_sum: the one-hot matmul needs the (G,) accumulator and a
     # (TILE, G) one-hot resident in VMEM.
     segment_sum_max_groups: int = MAX_MATMUL_SEGMENTS
@@ -186,14 +193,8 @@ def policy_from_env(env=None) -> DispatchPolicy:
     pack = _env_tristate(env, "REPRO_PACK")
     return DispatchPolicy(
         use_pallas=_env_tristate(env, "REPRO_USE_PALLAS"),
-        interpret=_env_tristate(env, "REPRO_PALLAS_INTERPRET"),
         bucketize_min_queries=_env_int(
             env, "REPRO_BUCKETIZE_MIN_QUERIES", base.bucketize_min_queries),
-        bucketize_max_vmem_boundaries=_env_int(
-            env, "REPRO_BUCKETIZE_MAX_VMEM_BOUNDARIES",
-            base.bucketize_max_vmem_boundaries),
-        rle_decode_min_rows=_env_int(
-            env, "REPRO_RLE_DECODE_MIN_ROWS", base.rle_decode_min_rows),
         segment_sum_max_groups=_env_int(
             env, "REPRO_SEGSUM_MAX_GROUPS", base.segment_sum_max_groups),
         enable_sort_free=True if sort_free is None else sort_free,
@@ -278,14 +279,13 @@ def unpack(packed) -> jax.Array:
     """Expand a ``PackedColumn`` buffer leaf to its logical int32 values.
 
     Pallas shift+mask kernel when the policy allows and the stream clears
-    the size thresholds, else the inline XLA expression (``ref_unpack``) —
+    the size threshold, else the inline XLA expression (``ref_unpack``) —
     which traces at the CALLER, so XLA fuses the extraction into the
     consuming op instead of materializing the full-width tensor.
     """
     pol = policy()
     n, words = packed.nrows, packed.words
-    if (pol.pallas_enabled() and n >= pol.unpack_min_vals
-            and 0 < words.shape[0] <= MAX_VMEM_WORDS):
+    if pol.pallas_enabled() and n >= pol.unpack_min_vals and words.shape[0]:
         _route("unpack", "kernel",
                f"n={n}>=unpack_min_vals={pol.unpack_min_vals}")
         return unpack_kernel(words, packed.bit_width, packed.offset, n,
@@ -293,102 +293,40 @@ def unpack(packed) -> jax.Array:
     _route("unpack", "ref",
            "pallas off" if not pol.pallas_enabled()
            else f"n={n}<unpack_min_vals={pol.unpack_min_vals}"
-           if n < pol.unpack_min_vals
-           else f"words={words.shape[0]} outside (0, {MAX_VMEM_WORDS}]")
+           if n < pol.unpack_min_vals else "empty words")
     return ref_mod.ref_unpack(words, packed.bit_width, packed.offset, n)
 
 
 def bucketize(boundaries: jax.Array, queries, right: bool = True) -> jax.Array:
     """torch.bucketize == searchsorted (right=True -> side='right').
 
-    ``queries`` may be a ``PackedColumn``: the Pallas route then runs the
-    FUSED unpack->bisect kernel (codes extracted in-register, never
-    materialized — the PK-FK probe / semi-join hot path on packed
-    dictionary FKs), and the XLA route inlines the unpack expression into
-    the searchsorted so fusion is XLA's to do.
+    ``queries`` may be a ``PackedColumn``; it is unpacked first (``unpack``
+    routes that). The counting kernel takes the call when the policy
+    allows, the queries clear the size threshold and the boundaries fit
+    one tile; XLA's searchsorted takes every other. The resident-boundary
+    binary-search kernels are off the route (``OFF_TPU_ROUTE``).
     """
     pol = policy()
     if _is_packed(queries):
-        n_b, n_q = boundaries.shape[0], queries.nrows
-        if (pol.pallas_enabled() and n_b > 0
-                and n_q >= pol.bucketize_min_queries
-                and n_b <= pol.bucketize_max_vmem_boundaries
-                and 0 < queries.words.shape[0] <= MAX_VMEM_WORDS
-                and _kernel_ok(boundaries)):
-            _route("bucketize", "kernel_packed_fused",
-                   f"n_q={n_q}>=bucketize_min_queries="
-                   f"{pol.bucketize_min_queries}")
-            return bucketize_packed_kernel(
-                boundaries, queries.words, queries.bit_width, queries.offset,
-                n_q, right, interpret=pol.interpret_mode())
-        _route("bucketize", "ref_unpack_inline",
-               "packed queries below kernel thresholds")
-        queries = ref_mod.ref_unpack(queries.words, queries.bit_width,
-                                     queries.offset, n_q)
+        queries = unpack(queries)
     n_b, n_q = boundaries.shape[0], queries.shape[0]
-    if (pol.pallas_enabled() and n_b > 0
+    if (pol.pallas_enabled() and 0 < n_b <= COUNT_KERNEL_MAX_BOUNDARIES
             and n_q >= pol.bucketize_min_queries
             and _kernel_ok(boundaries, queries)):
-        interp = pol.interpret_mode()
-        if n_b <= pol.bucketize_max_vmem_boundaries:
-            _route("bucketize", "kernel",
-                   f"n_q={n_q}>=bucketize_min_queries="
-                   f"{pol.bucketize_min_queries}, n_b={n_b} fits VMEM")
-            return bucketize_kernel(boundaries, queries, right,
-                                    interpret=interp)
         _route("bucketize", "count_kernel",
-               f"n_b={n_b}>bucketize_max_vmem_boundaries="
-               f"{pol.bucketize_max_vmem_boundaries}")
+               f"n_q={n_q}>=bucketize_min_queries="
+               f"{pol.bucketize_min_queries}, n_b={n_b}<="
+               f"{COUNT_KERNEL_MAX_BOUNDARIES}")
         return bucketize_count_kernel(boundaries, queries, right,
-                                      interpret=interp)
+                                      interpret=pol.interpret_mode())
     _route("bucketize", "xla",
            "pallas off" if not pol.pallas_enabled()
            else f"n_q={n_q}<bucketize_min_queries={pol.bucketize_min_queries}"
-           if n_q < pol.bucketize_min_queries else "dtype/empty boundaries")
+           if n_q < pol.bucketize_min_queries
+           else f"n_b={n_b} outside (0, {COUNT_KERNEL_MAX_BOUNDARIES}]"
+           if not 0 < n_b <= COUNT_KERNEL_MAX_BOUNDARIES else "dtype")
     side = "right" if right else "left"
     return jnp.searchsorted(boundaries, queries, side=side).astype(jnp.int32)
-
-
-def maybe_rle_decode(values, starts, ends, n, nrows: int, fill=0):
-    """Kernel-decoded dense [nrows] array, or None when the policy routes
-    to the caller's XLA formulation (the O(n) scatter+cumsum sweep in
-    ``encodings.decode_rle_values`` — the call site owns its fallback
-    because it is already the tuned XLA implementation, and it unpacks
-    packed run values lazily itself).
-
-    ``values`` may be a ``PackedColumn``: the kernel route then gathers
-    run values straight out of the packed words (run id -> lane/shift,
-    fused — no unpacked value buffer in HBM).
-    """
-    pol = policy()
-    if not (pol.pallas_enabled() and nrows >= pol.rle_decode_min_rows
-            and starts.shape[0] > 0 and _kernel_ok(starts, ends)):
-        _route("rle_decode", "xla",
-               "pallas off" if not pol.pallas_enabled()
-               else f"nrows={nrows}<rle_decode_min_rows="
-               f"{pol.rle_decode_min_rows}"
-               if nrows < pol.rle_decode_min_rows else "dtype/empty runs")
-        return None
-    if _is_packed(values):
-        if not (0 < values.words.shape[0] <= MAX_VMEM_WORDS):
-            _route("rle_decode", "xla",
-                   f"packed words={values.words.shape[0]} outside "
-                   f"(0, {MAX_VMEM_WORDS}]")
-            return None
-        _route("rle_decode", "kernel_packed_fused",
-               f"nrows={nrows}>=rle_decode_min_rows={pol.rle_decode_min_rows}")
-        return rle_decode_packed_kernel(
-            values.words, values.bit_width, values.offset, starts.shape[0],
-            starts, ends, jnp.asarray(n, jnp.int32), nrows, fill,
-            interpret=pol.interpret_mode())
-    if not _kernel_ok(values):
-        _route("rle_decode", "xla", f"value dtype {values.dtype} not routed")
-        return None
-    _route("rle_decode", "kernel",
-           f"nrows={nrows}>=rle_decode_min_rows={pol.rle_decode_min_rows}")
-    return rle_decode_kernel(values, starts, ends,
-                             jnp.asarray(n, jnp.int32), nrows, fill,
-                             interpret=pol.interpret_mode())
 
 
 def segment_sum(values: jax.Array, segment_ids: jax.Array,

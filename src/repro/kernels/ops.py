@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels with XLA fallbacks.
 
-On this CPU container the kernels run in interpret mode (``interpret=True``
-executes the kernel body in Python for correctness validation); on TPU they
-compile natively. ``use_pallas=False`` routes to the pure-jnp reference
+Off the TPU the kernels run in interpret mode (``interpret=True`` executes
+the kernel body in Python for correctness validation); on the TPU they
+compile natively. ``interpret=None`` takes the dispatch policy's
+``interpret_mode()``. ``use_pallas=False`` routes to the pure-jnp reference
 implementations so the wrappers work on any backend.
 
 These wrappers are the *explicit-choice* API (tests, microbenches). The
@@ -26,15 +27,14 @@ from repro.kernels.bucketize import (
     bucketize_count_kernel,
     bucketize_kernel,
 )
-from repro.kernels.dispatch import MAX_MATMUL_SEGMENTS
+from repro.kernels.dispatch import MAX_MATMUL_SEGMENTS, policy
 from repro.kernels.rle_decode import rle_decode_kernel
 from repro.kernels.segment_reduce import segment_sum_kernel
 from repro.kernels.unpack import unpack_kernel
 
 
-def default_interpret() -> bool:
-    """Pallas must interpret on non-TPU backends."""
-    return jax.default_backend() != "tpu"
+def _interpret(interpret: bool | None) -> bool:
+    return policy().interpret_mode() if interpret is None else interpret
 
 
 @partial(jax.jit, static_argnames=("right", "use_pallas", "interpret"))
@@ -43,7 +43,7 @@ def bucketize(boundaries, queries, right: bool = True, use_pallas: bool = False,
     if (not use_pallas or boundaries.shape[0] == 0
             or queries.shape[0] == 0):
         return ref.ref_bucketize(boundaries, queries, right)
-    interp = default_interpret() if interpret is None else interpret
+    interp = _interpret(interpret)
     if boundaries.shape[0] <= MAX_VMEM_BOUNDARIES:
         return bucketize_kernel(boundaries, queries, right, interpret=interp)
     return bucketize_count_kernel(boundaries, queries, right, interpret=interp)
@@ -58,7 +58,7 @@ def rle_decode(values, starts, ends, n, nrows: int, fill=0,
         return jnp.full((nrows,), fill, values.dtype)
     if not use_pallas:
         return ref.ref_rle_decode(values, starts, ends, n, nrows, fill)
-    interp = default_interpret() if interpret is None else interpret
+    interp = _interpret(interpret)
     return rle_decode_kernel(values, starts, ends, n, nrows, fill, interpret=interp)
 
 
@@ -70,7 +70,7 @@ def unpack(words, bit_width: int, offset, nvals: int,
         return jnp.zeros((0,), jnp.int32)
     if not use_pallas:
         return ref.ref_unpack(words, bit_width, offset, nvals)
-    interp = default_interpret() if interpret is None else interpret
+    interp = _interpret(interpret)
     return unpack_kernel(words, bit_width, offset, nvals, interpret=interp)
 
 
@@ -80,6 +80,6 @@ def segment_reduce(values, segment_ids, num_segments: int, reduce: str = "sum",
     if (not use_pallas or reduce != "sum" or num_segments > MAX_MATMUL_SEGMENTS
             or num_segments == 0 or values.shape[0] == 0):
         return ref.ref_segment_reduce(values, segment_ids, num_segments, reduce)
-    interp = default_interpret() if interpret is None else interpret
+    interp = _interpret(interpret)
     return segment_sum_kernel(values.astype(jnp.float32), segment_ids,
                               num_segments, interpret=interp)

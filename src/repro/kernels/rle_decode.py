@@ -7,7 +7,9 @@ torch.repeat_interleave-style expansion (DESIGN.md §3).
 
 Run metadata (values/starts/ends) is staged HBM->VMEM once per grid step;
 output row tiles stream through the grid. VMEM = 3·R + TILE; work
-O(nrows · log R).
+O(nrows · log R). The run-value fetch and ``_bsearch`` are per-lane 1-D
+gathers, which Mosaic refuses, so dispatch never routes this kernel
+(``dispatch.OFF_TPU_ROUTE``).
 """
 from __future__ import annotations
 

@@ -17,12 +17,14 @@ full-width tensor never lands in HBM:
   * ``rle_decode_packed_kernel`` — RLE expansion gathering the run value
     from packed words (run id -> word/shift -> value, one fused pass).
 
-The packed words block stays VMEM-resident per grid step (like the
-boundary block in bucketize.py); output tiles stream through the grid.
-Word extraction per value: ``w = i*b >> 5`` may straddle two lanes, so two
-loads + shift + or + mask — branch-free, one VPU op chain per element.
-``i*b`` is computed as ``(i>>5)*b + ((i&31)*b >> 5)`` to stay inside
-int32 for any capacity the engine supports.
+``unpack_kernel`` streams word tiles and extracts at static shifts (see
+``_unpack_body``). The two fused kernels keep the packed words block
+VMEM-resident per grid step (like the boundary block in bucketize.py) and
+extract with a per-lane gather: ``w = i*b >> 5`` may straddle two lanes,
+so two loads + shift + or + mask. ``i*b`` is computed as
+``(i>>5)*b + ((i&31)*b >> 5)`` to stay inside int32 for any capacity the
+engine supports. Mosaic lowers no 1-D gather, so dispatch routes neither
+fused kernel on the TPU (``dispatch.OFF_TPU_ROUTE``).
 """
 from __future__ import annotations
 
@@ -31,12 +33,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.bucketize import _bsearch
 
 VAL_TILE = 2048
-# VMEM budget for the resident packed-words block (uint32 lanes).
-MAX_VMEM_WORDS = 1 << 21  # 2M words = 8 MiB
 
 
 def _extract(words: jax.Array, idx: jax.Array, bit_width: int,
@@ -78,31 +79,52 @@ def _to_signed(codes: jax.Array, offset) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _unpack_body(bit_width: int, nwords: int, w_ref, o_ref_scalar, out_ref):
-    i = pl.program_id(0)
-    idx = i * VAL_TILE + jax.lax.iota(jnp.int32, VAL_TILE)
-    codes = _extract(w_ref[...], idx, bit_width, nwords)
-    out_ref[...] = _to_signed(codes, o_ref_scalar[0])
+ROW_VALS = 128  # values per output row: one lane-width
+ROW_TILE = 512  # output rows per grid step (65536 values)
+
+
+def _unpack_body(bit_width: int, w_ref, off_ref, out_ref):
+    """A row of 128 values spans exactly ``4*b`` words, so value ``j`` of
+    every row reads words ``(j*b)>>5`` (and the next, when it straddles)
+    at a shift known at trace time: static sublane slices of the
+    transposed ``(4b, ROW_TILE)`` word block, no gather (Mosaic lowers
+    none over a 1-D block)."""
+    b = bit_width
+    w = w_ref[...]
+    rows = []
+    for j in range(ROW_VALS):
+        wj, oj = (j * b) >> 5, (j * b) & 31
+        v = jax.lax.shift_right_logical(w[wj:wj + 1], jnp.uint32(oj))
+        if oj + b > 32:
+            v = v | jax.lax.shift_left(w[wj + 1:wj + 2], jnp.uint32(32 - oj))
+        if b < 32:
+            v = v & jnp.uint32((1 << b) - 1)
+        rows.append(v)
+    codes = jnp.concatenate(rows, axis=0)  # (ROW_VALS, ROW_TILE)
+    out_ref[...] = _to_signed(codes.T, off_ref[0])
 
 
 def unpack_kernel(words: jax.Array, bit_width: int, offset, nvals: int,
                   interpret: bool = False) -> jax.Array:
-    """Expand a packed stream to int32[nvals]."""
-    nwords = words.shape[0]
-    n_pad = -(-nvals // VAL_TILE) * VAL_TILE
+    """Expand a packed stream to int32[nvals]. Words stream through the
+    grid in row tiles, so VMEM use is fixed whatever the stream length."""
+    b = bit_width
+    rows = -(-nvals // ROW_VALS)
+    rows_pad = -(-rows // ROW_TILE) * ROW_TILE
+    words = jnp.pad(words, (0, rows_pad * 4 * b - words.shape[0]))
     off_arr = jnp.asarray(offset, jnp.int32).reshape((1,))
     out = pl.pallas_call(
-        functools.partial(_unpack_body, bit_width, nwords),
-        grid=(n_pad // VAL_TILE,),
+        functools.partial(_unpack_body, b),
+        grid=(rows_pad // ROW_TILE,),
         in_specs=[
-            pl.BlockSpec((nwords,), lambda i: (0,)),  # words resident
-            pl.BlockSpec((1,), lambda i: (0,)),  # offset scalar
+            pl.BlockSpec((4 * b, ROW_TILE), lambda i: (0, i)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # offset scalar
         ],
-        out_specs=pl.BlockSpec((VAL_TILE,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int32),
+        out_specs=pl.BlockSpec((ROW_TILE, ROW_VALS), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows_pad, ROW_VALS), jnp.int32),
         interpret=interpret,
-    )(words, off_arr)
-    return out[:nvals]
+    )(words.reshape(rows_pad, 4 * b).T, off_arr)
+    return out.reshape(-1)[:nvals]
 
 
 # ---------------------------------------------------------------------------

@@ -32,7 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs
 from repro.distributed import sharding as Sh
 from repro.launch import hlo_cost
-from repro.launch.mesh import activate_mesh, make_production_mesh, data_axes
+from repro.launch.mesh import make_production_mesh, data_axes
 from repro.models import model as M
 from repro.train import optimizer as opt
 from repro.train import step as TS
@@ -192,9 +192,8 @@ def run_cell(arch: str, shape: str, multi_pod: bool) -> Dict[str, Any]:
     t0 = time.perf_counter()
     jitted, arg_shapes, cfg = build_cell(arch, shape, mesh)
     # the ambient mesh must be visible inside jit tracing — the MoE
-    # shard_map paths key off it (set_mesh on newer jax, `with mesh:`
-    # under the pinned 0.4.x line — launch.mesh.activate_mesh)
-    with activate_mesh(mesh):
+    # shard_map paths key off it
+    with jax.sharding.set_mesh(mesh):
         lowered = jitted.lower(*arg_shapes)
         t_lower = time.perf_counter() - t0
         t1 = time.perf_counter()

@@ -1,4 +1,4 @@
-"""Production mesh definitions + jax-version compatibility shims.
+"""Production mesh definitions.
 
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
 this module never touches jax device state — required because the dry-run
@@ -9,76 +9,30 @@ Production topology (TPU v5e):
   multi-pod  : (2, 16, 16)   axes ("pod", "data", "model") — 512 chips
 Batch shards over ("pod", "data"); model-parallel dims over "model".
 
-Version shims: newer jax exposes ``axis_types=AxisType.Auto`` meshes,
-``jax.sharding.set_mesh`` and ``jax.shard_map``; the pinned 0.4.x line has
-none of these — there the physical ``Mesh`` itself is the (legacy
-thread-resources) context manager, ``jax.make_mesh`` takes no axis types
-and shard_map lives under ``jax.experimental``. The ``*_compat`` helpers
-paper over the difference so the sharded code paths and the multi-device
-subprocess tests run unchanged on either line.
+Meshes carry ``AxisType.Auto`` axes; callers install one as the ambient
+mesh with ``jax.sharding.set_mesh`` and read it back with
+``jax.sharding.get_abstract_mesh``.
 """
 from __future__ import annotations
 
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with Auto axis types when the jax version has
-    them, plain device mesh otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def activate_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh seen by
-    tracing (``get_abstract_mesh`` / thread resources): ``set_mesh`` on
-    newer jax, the legacy ``with mesh:`` on 0.4.x (a Mesh is its own
-    context manager there)."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
-
-
-def abstract_mesh_compat():
-    """The ambient mesh for trace-time dataflow decisions (layers.moe), or
-    None: ``get_abstract_mesh`` on newer jax; on 0.4.x the physical mesh
-    installed by ``with mesh:`` (via the legacy thread resources)."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            return get_abstract()
-        except Exception:  # noqa: BLE001 - no mesh installed
-            return None
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:  # noqa: BLE001 - internal layout changed
-        return None
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (new) or ``jax.experimental.shard_map`` (0.4.x)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke tests of the sharded code paths."""
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

@@ -303,18 +303,13 @@ def _dispatch_combine_local(x, slot, gate, overflow, E, C, d, ffn):
 
 
 def _moe_mesh(expert_axis, cap_axis):
-    """Active ambient mesh + model-axis size, if usable for shard_map.
-
-    Resolved through the mesh compat shim (launch.mesh): the abstract mesh
-    installed by ``set_mesh`` on newer jax, the legacy thread-resources
-    physical mesh under 0.4.x's ``with mesh:``.
-    """
+    """Active ambient mesh + model-axis size, if usable for shard_map: the
+    abstract mesh installed by ``jax.sharding.set_mesh``."""
     axis = expert_axis or cap_axis
     if axis is None:
         return None, None, 1
-    from repro.launch.mesh import abstract_mesh_compat
-    am = abstract_mesh_compat()
-    if am is None or am.empty or axis not in am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or axis not in am.axis_names:
         return None, None, 1
     return am, axis, am.shape[axis]
 
@@ -403,10 +398,9 @@ def moe(params, x, top_k: int, capacity_factor: float = 1.25,
                 x_l, slot_l, gate_l, overflow, e_pad, capacity, d, ffn)
             return y_l.astype(x_l.dtype)
 
-        from repro.launch.mesh import shard_map_compat
         w_specs = (P(model_axis, fsdp0, None), P(model_axis, fsdp0, None),
                    P(model_axis, None, fsdp0))
-        sm = shard_map_compat(
+        sm = jax.shard_map(
             body, mesh=am,
             in_specs=(P(group_axes, model_axis, None),
                       P(group_axes, model_axis), P(group_axes, model_axis))

@@ -5,8 +5,8 @@ Four layers, mirroring the structure of tests/test_pallas_kernels.py:
   1. pack/unpack round-trip — hypothesis property across bit widths 1-32
      (width-32 modular passthrough, empty buffers, pow2 padding tails,
      negative centered values) + interpret-mode kernel parity,
-  2. dispatch routing units (unpack / fused bucketize / fused rle_decode,
-     REPRO_PACK* policy parsing),
+  2. dispatch routing units (unpack, bucketize over packed queries, the
+     fused kernels off the TPU route, REPRO_PACK* policy parsing),
   3. engine conformance — packed ingest must be BIT-IDENTICAL to the
      unpacked path for all six encodings, single-table and partitioned,
   4. the transfer contract — packed partitions ship strictly fewer H2D
@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import compress
+from repro.core import compress, primitives
 from repro.core.encodings import PackedColumn, unpack_values
 from repro.core.partition import (
     PartitionedQuery,
@@ -29,6 +29,7 @@ from repro.core.partition import (
 from repro.core.plan import Query, col
 from repro.core.table import Table
 from repro.kernels import dispatch, ops, ref
+from repro.kernels import unpack as unpack_mod
 
 # ---------------------------------------------------------------------------
 # 1. pack/unpack round-trip
@@ -167,17 +168,22 @@ def test_dispatch_unpack_routing(rng, monkeypatch):
 
 
 def test_dispatch_bucketize_packed_routing(rng, monkeypatch):
-    calls = _count_kernel(monkeypatch, "bucketize_packed_kernel")
+    """Packed queries are unpacked first, then take the unpacked route:
+    the fused unpack->bisect kernel is off the route (Mosaic refuses its
+    1-D gather), so the counting kernel answers when Pallas is on."""
+    assert "bucketize_packed_kernel" in dispatch.OFF_TPU_ROUTE
+    calls = _count_kernel(monkeypatch, "bucketize_count_kernel")
+    unpacks = _count_kernel(monkeypatch, "unpack_kernel")
     v, pc = _packed(rng, n=200, b=9, lo=0)
     bnd = jnp.asarray(np.sort(rng.integers(0, 512, 37)).astype(np.int32))
     want = np.searchsorted(np.asarray(bnd), v, side="right")
     got = dispatch.bucketize(bnd, pc, right=True)  # CPU auto: XLA
-    assert not calls
+    assert not calls and not unpacks
     np.testing.assert_array_equal(np.asarray(got), want)
     with dispatch.overrides(use_pallas=True, interpret=True,
-                            bucketize_min_queries=1):
+                            bucketize_min_queries=1, unpack_min_vals=1):
         got = dispatch.bucketize(bnd, pc, right=True)
-    assert len(calls) == 1
+    assert len(calls) == 1 and len(unpacks) == 1
     np.testing.assert_array_equal(np.asarray(got), want)
     # below the query threshold: no kernel even when forced
     with dispatch.overrides(use_pallas=True, interpret=True,
@@ -186,8 +192,7 @@ def test_dispatch_bucketize_packed_routing(rng, monkeypatch):
     assert len(calls) == 1
 
 
-def test_dispatch_rle_decode_packed_routing(rng, monkeypatch):
-    calls = _count_kernel(monkeypatch, "rle_decode_packed_kernel")
+def test_dispatch_rle_decode_packed_routing(rng):
     nrows = 8192
     starts = np.sort(rng.choice(nrows, 16, replace=False)).astype(np.int32)
     ends = np.concatenate([starts[1:] - 1, [nrows - 1]]).astype(np.int32)
@@ -196,10 +201,18 @@ def test_dispatch_rle_decode_packed_routing(rng, monkeypatch):
     pc = PackedColumn(words=words, nrows=16, bit_width=4, offset=-5)
     args = (pc, jnp.asarray(starts), jnp.asarray(ends),
             jnp.asarray(16, jnp.int32), nrows)
-    assert dispatch.maybe_rle_decode(*args) is None  # CPU auto: caller's XLA
+    # off the route whatever the policy: run expansion over packed run
+    # values stages no kernel (16 values stay below unpack_min_vals)
+    assert "rle_decode_packed_kernel" in dispatch.OFF_TPU_ROUTE
     with dispatch.overrides(use_pallas=True, interpret=True):
-        got = dispatch.maybe_rle_decode(*args)
-    assert len(calls) == 1 and got is not None
+        jaxpr = str(jax.make_jaxpr(
+            lambda w, s, e, n: primitives.rle_to_plain(
+                PackedColumn(words=w, nrows=16, bit_width=4, offset=-5),
+                s, e, n, nrows))(words, *args[1:4]))
+    assert "pallas_call" not in jaxpr
+    # the kernel itself still matches its reference in interpret mode
+    got = unpack_mod.rle_decode_packed_kernel(
+        words, 4, -5, 16, *args[1:], interpret=True)
     want = ref.ref_rle_decode(jnp.asarray(vals.astype(np.int32)),
                               jnp.asarray(starts), jnp.asarray(ends),
                               jnp.asarray(16, jnp.int32), nrows)
@@ -317,8 +330,7 @@ def test_packed_pipeline_forced_kernels_match(rng):
 
     base = run()
     with dispatch.overrides(use_pallas=True, interpret=True,
-                            bucketize_min_queries=1, rle_decode_min_rows=1,
-                            unpack_min_vals=1):
+                            bucketize_min_queries=1, unpack_min_vals=1):
         routed = run()
     np.testing.assert_array_equal(np.asarray(base.keys["k"]),
                                   np.asarray(routed.keys["k"]))

@@ -8,6 +8,7 @@ wrappers must route these to the reference path), out-of-range ids, and
 both ``right=`` sides.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -138,14 +139,15 @@ def test_segment_sum_all_ids_out_of_range(rng):
 def test_policy_from_env_parsing():
     pol = dispatch.policy_from_env({
         "REPRO_USE_PALLAS": "1",
-        "REPRO_PALLAS_INTERPRET": "0",
+        "REPRO_PALLAS_INTERPRET": "0",  # no such variable: ignored
         "REPRO_SORT_FREE": "off",
         "REPRO_SORT_FREE_MAX_DOMAIN": "4096",
         "REPRO_BUCKETIZE_MIN_QUERIES": "16",
         "REPRO_SEGSUM_MAX_GROUPS": "128",
     })
     assert pol.use_pallas is True and pol.pallas_enabled()
-    assert pol.interpret is False and not pol.interpret_mode()
+    # interpret mode is set in code only, never from the environment
+    assert pol.interpret is None and pol.interpret_mode()
     assert pol.enable_sort_free is False
     assert pol.sort_free_max_domain == 4096
     assert pol.bucketize_min_queries == 16
@@ -169,7 +171,8 @@ def _count_kernel(monkeypatch, name):
 
 
 def test_dispatch_bucketize_routing(rng, monkeypatch):
-    calls = _count_kernel(monkeypatch, "bucketize_kernel")
+    assert "bucketize_kernel" in dispatch.OFF_TPU_ROUTE
+    calls = _count_kernel(monkeypatch, "bucketize_count_kernel")
     b = jnp.asarray(np.sort(rng.integers(0, 100, 50)).astype(np.int32))
     q = jnp.asarray(rng.integers(0, 100, 64).astype(np.int32))
     want = np.asarray(jnp.searchsorted(b, q, side="right"))
@@ -188,6 +191,16 @@ def test_dispatch_bucketize_routing(rng, monkeypatch):
                             bucketize_min_queries=1000):
         dispatch.bucketize(b, q, right=True)
     assert len(calls) == 1
+    # more boundaries than one counting tile: XLA's searchsorted
+    wide = jnp.asarray(np.arange(dispatch.COUNT_KERNEL_MAX_BOUNDARIES + 1,
+                                 dtype=np.int32))
+    with dispatch.overrides(use_pallas=True, interpret=True,
+                            bucketize_min_queries=1):
+        got = dispatch.bucketize(wide, q, right=True)
+    assert len(calls) == 1
+    np.testing.assert_array_equal(
+        np.asarray(got), np.searchsorted(np.asarray(wide), np.asarray(q),
+                                         side="right"))
 
 
 def test_dispatch_segment_sum_routing(rng, monkeypatch):
@@ -208,24 +221,29 @@ def test_dispatch_segment_sum_routing(rng, monkeypatch):
         assert len(calls) == 1
 
 
-def test_dispatch_rle_decode_routing(rng, monkeypatch):
-    calls = _count_kernel(monkeypatch, "rle_decode_kernel")
+def _rle_expansion_jaxpr(*args):
+    """Run expansion as the engine stages it, under the on-chip policy."""
+    from repro.core.primitives import rle_to_plain
+    with dispatch.overrides(use_pallas=True, interpret=True):
+        return str(jax.make_jaxpr(
+            lambda v, s, e, n: rle_to_plain(v, s, e, n, args[-1]))(*args[:4]))
+
+
+def test_dispatch_rle_decode_routing(rng):
+    """The RLE kernel is off the route (Mosaic refuses its 1-D gather):
+    run expansion stages no kernel even with Pallas forced on."""
+    assert "rle_decode_kernel" in dispatch.OFF_TPU_ROUTE
     nrows = 8192
     starts = np.sort(rng.choice(nrows, 16, replace=False)).astype(np.int32)
     ends = np.concatenate([starts[1:] - 1, [nrows - 1]]).astype(np.int32)
     vals = rng.integers(0, 9, 16).astype(np.int32)
     args = (jnp.asarray(vals), jnp.asarray(starts), jnp.asarray(ends),
             jnp.asarray(16, jnp.int32), nrows)
-    assert dispatch.maybe_rle_decode(*args) is None  # CPU auto: caller's XLA
-    with dispatch.overrides(use_pallas=True, interpret=True):
-        got = dispatch.maybe_rle_decode(*args)
-        assert len(calls) == 1 and got is not None
-        np.testing.assert_array_equal(np.asarray(got),
-                                      np.asarray(ref.ref_rle_decode(*args)))
-        # tiny columns stay on the fused XLA sweep
-        assert dispatch.maybe_rle_decode(
-            *args[:4], nrows=dispatch.policy().rle_decode_min_rows - 1) is None
-        assert len(calls) == 1
+    assert "pallas_call" not in _rle_expansion_jaxpr(*args)
+    # the kernel itself still matches its reference in interpret mode
+    got = ops.rle_decode(*args, use_pallas=True, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(ref.ref_rle_decode(*args)))
 
 
 def test_dispatch_routed_pipeline_matches_unrouted(rng):
@@ -247,7 +265,7 @@ def test_dispatch_routed_pipeline_matches_unrouted(rng):
 
     base = run_once()
     with dispatch.overrides(use_pallas=True, interpret=True,
-                            bucketize_min_queries=1, rle_decode_min_rows=1):
+                            bucketize_min_queries=1, unpack_min_vals=1):
         routed = run_once()
     assert int(base.num_groups) == int(routed.num_groups)
     np.testing.assert_array_equal(np.asarray(base.keys["k"]),

@@ -113,18 +113,17 @@ def _run_subprocess(code: str) -> str:
 
 @pytest.mark.slow
 def test_moe_ep_parity_8dev():
-    """EP parity under the mesh compat shims (launch.mesh): AxisType/
-    set_mesh on newer jax, legacy `with mesh:` thread resources on the
-    pinned 0.4.x line (the seed's direct set_mesh calls xfailed there)."""
+    """EP parity: the shard_map path under an ambient ``set_mesh`` mesh
+    matches the local path."""
     out = _run_subprocess("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro.launch.mesh import activate_mesh, make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.models import layers as L
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.standard_normal((4, 16, 16)), jnp.float32)
-        mesh = make_mesh_compat((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         p = L.init_moe(jax.random.PRNGKey(6), 16, 32, 6, jnp.float32, n_padded=8)
-        with activate_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             y_ep, _ = jax.jit(lambda p_, x_: L.moe(
                 p_, x_, 2, 100.0, group_axes=('data',),
                 expert_axis='model'))(p, x)
@@ -144,11 +143,11 @@ def test_mini_dryrun_cell_8dev():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro import configs
         from repro.distributed import sharding as Sh
-        from repro.launch.mesh import activate_mesh, make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.models import model as M
         from repro.train import step as TS, optimizer as opt
         from repro.launch import hlo_cost
-        mesh = make_mesh_compat((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         cfg = dataclasses.replace(
             configs.get_smoke_config('qwen2_1p5b'), d_model=64, n_heads=4,
             n_kv_heads=2, d_ff=128, act_batch_axes=('data',),
@@ -163,7 +162,7 @@ def test_mini_dryrun_cell_8dev():
         bsh = jax.tree.map(lambda s: NamedSharding(mesh, s),
                            Sh.batch_shardings(bshape, mesh, 8))
         fn = TS.make_train_step(cfg, tcfg)
-        with activate_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             compiled = jax.jit(fn, in_shardings=(sh, bsh),
                                out_shardings=(sh, NamedSharding(mesh, P()))
                                ).lower(ss, bshape).compile()
@@ -191,10 +190,10 @@ def test_elastic_checkpoint_reshard_8dev():
         from jax.sharding import NamedSharding
         import repro.train as T
         from repro import configs
-        from repro.launch.mesh import make_mesh_compat
+        from repro.launch.mesh import make_mesh
         from repro.models import model as M
         from repro.distributed import sharding as Sh
-        mesh = make_mesh_compat((2, 4), ('data', 'model'))
+        mesh = make_mesh((2, 4), ('data', 'model'))
         cfg = configs.get_smoke_config('smollm_360m')
         like = jax.eval_shape(lambda k: M.init_params(cfg, k),
                               jax.random.PRNGKey(0))

@@ -1,0 +1,187 @@
+"""Compiles for a described TPU v5e chip, with no chip attached.
+
+The TPU compiler is installed with JAX, so the kernels dispatch routes on
+the TPU and the query programs of the engine's main path are compiled here
+exactly as the chip would compile them, under the on-chip policy (Pallas
+on, interpret mode off). Nothing runs: these tests catch what Mosaic and
+XLA:TPU refuse — unaligned blocks, gathers with no lowering, VMEM
+overruns — which interpret-mode tests cannot see. Kernels compile at
+partition size (2^22 rows) and at their VMEM upper bounds.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and under pytest-xdist
+only the worker that runs this file may try.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import compress, telemetry
+from repro.core.partition import (PartitionedQuery, PartitionedTable,
+                                  base_masked_program)
+from repro.core.plan import Query, col
+from repro.core.table import Table
+from repro.kernels import dispatch
+from repro.kernels.bucketize import bucketize_count_kernel
+from repro.kernels.segment_reduce import segment_sum_kernel
+from repro.kernels.topk import MAX_KERNEL_K, topk_kernel
+from repro.kernels.unpack import unpack_kernel
+
+from benchmarks.bench_production import _semi_keys, make_star
+
+PARTITION_ROWS = 1 << 22
+QUERY_ROWS = 1 << 17
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # the TPU compiler logs under /tmp unless told otherwise
+    old_log_dir = os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back without one: keep the cache out of it
+    old_cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", old_cache)
+        if old_log_dir == "disabled":
+            os.environ.pop("TPU_LOG_DIR", None)
+
+
+def _shapes(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.result_type(a),
+                                       sharding=sharding), tree)
+
+
+def _compile_text(fn, args, sharding) -> str:
+    with dispatch.overrides(use_pallas=True, interpret=False):
+        return jax.jit(fn).lower(*_shapes(args, sharding)).compile().as_text()
+
+
+def _kernel_case(name):
+    n = PARTITION_ROWS
+    i32 = np.zeros((n,), np.int32)
+    f32 = np.zeros((n,), np.float32)
+    if name.startswith("unpack"):
+        b = int(name.split("_b")[1])
+        return (lambda w, off: unpack_kernel(w, b, off, n),
+                (np.zeros((n * b // 32,), np.uint32), np.int32(0)))
+    if name.startswith("count"):
+        bounds = np.zeros((dispatch.COUNT_KERNEL_MAX_BOUNDARIES,),
+                          i32.dtype if name.endswith("int32") else f32.dtype)
+        queries = i32 if name.endswith("int32") else f32
+        return (lambda b, q: bucketize_count_kernel(b, q), (bounds, queries))
+    if name.startswith("segsum"):
+        g = int(name.split("_g")[1])
+        return (lambda v, ids: segment_sum_kernel(v, ids, g), (f32, i32))
+    k = int(name.split("_k")[1].split("_")[0])
+    return (lambda v: topk_kernel(v, k),
+            (i32 if name.endswith("int32") else f32,))
+
+
+@pytest.mark.parametrize("name", [
+    # unpack streams word tiles: b=32 is its widest block
+    "unpack_b1", "unpack_b7", "unpack_b24", "unpack_b32",
+    # the counting bucketize at its boundary bound
+    "count_int32", "count_float32",
+    # segment_sum at a typical group count and at its VMEM bound
+    "segsum_g128", f"segsum_g{dispatch.MAX_MATMUL_SEGMENTS}",
+    # top-k at a typical k and at its bound
+    "topk_k10_float32", f"topk_k{MAX_KERNEL_K}_float32",
+    f"topk_k{MAX_KERNEL_K}_int32",
+])
+def test_routed_kernel_compiles(one_chip, name):
+    fn, args = _kernel_case(name)
+    assert "tpu_custom_call" in _compile_text(fn, args, one_chip)
+
+
+def _star(n, seed=0):
+    return make_star(np.random.default_rng(seed), n)
+
+
+def _routes_while(fn):
+    """Kernel routes dispatch took while ``fn`` traced (trace-time)."""
+    before = telemetry.registry().counters()
+    with dispatch.overrides(enable_trace=True):
+        out = fn()
+    after = telemetry.registry().counters()
+    taken = {k for k, v in after.items()
+             if k.startswith("route.") and v > before.get(k, 0)}
+    return out, taken
+
+
+QUERIES = {
+    "filter_groupby": (
+        lambda q: q.filter(col("c1") < 8).groupby(
+            ["c0"], {"s": ("sum", "measure"), "c": ("count", None)},
+            num_groups_cap=8),
+        {"route.segment_sum.kernel"}),
+    "semijoin_aggregate": (
+        lambda q: q.semi_join("c4", _semi_keys(np.random.default_rng(1),
+                                               1000, 0.5)).aggregate(
+            {"s": ("sum", "measure"), "c": ("count", None)}),
+        {"route.segment_sum.kernel"}),
+    "topk": (
+        lambda q: q.filter(col("c1") < 8).order_by("measure",
+                                                   descending=True, limit=10),
+        {"route.topk.kernel"}),
+}
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["unpacked", "packed"])
+@pytest.mark.parametrize("shape", sorted(QUERIES))
+def test_query_program_compiles(one_chip, shape, packed):
+    table = Table.from_arrays(
+        _star(QUERY_ROWS), cfg=compress.CompressionConfig(plain_threshold=1000),
+        pack=packed)
+    stage, kernels = QUERIES[shape]
+    q = stage(Query(table))
+    args = (table.columns, tuple(q._prepare_inputs()))
+    text, routes = _routes_while(
+        lambda: _compile_text(q.build(), args, one_chip))
+    assert kernels <= routes, routes
+    assert "tpu_custom_call" in text
+
+
+def test_production_q1_partition_program_compiles(one_chip):
+    """The paper's production Q1 template (7 semi-joins, a PK-FK join, a
+    SUM group-by), as the streamed executor compiles it for one packed
+    partition of 2^22 rows."""
+    rng = np.random.default_rng(2)
+    table = PartitionedTable.from_arrays(
+        _star(PARTITION_ROWS), cfg=compress.CompressionConfig(
+            plain_threshold=1000), partition_rows=PARTITION_ROWS, pack=True)
+    dim = Table.from_arrays({
+        "c6": np.arange(16000, dtype=np.int32),
+        "d6_cat": (np.arange(16000, dtype=np.int32) % 97).astype(np.int32),
+    }, cfg=compress.CompressionConfig(plain_threshold=1000))
+    q = PartitionedQuery(table)
+    for c, card in {"c2": 64, "c3": 256, "c4": 1000, "c5": 4000, "c8": 50,
+                    "c9": 200, "c11": 30}.items():
+        q = q.semi_join(c, _semi_keys(rng, card, 0.5))
+    q = q.join(dim, fk="c6", cols=["d6_cat"]).groupby(
+        ["d6_cat"], {"s": ("sum", "measure"), "c": ("count", None)},
+        num_groups_cap=128)
+    args = (table.partitions[0].table.columns, tuple(q._prepare_inputs()),
+            np.int32(PARTITION_ROWS))
+    text, routes = _routes_while(lambda: _compile_text(
+        base_masked_program(q.build(partial=True)), args, one_chip))
+    assert {"route.segment_sum.kernel", "route.bucketize.count_kernel",
+            "route.unpack.kernel"} <= routes, routes
+    assert "tpu_custom_call" in text

@@ -62,9 +62,7 @@ def test_checkpoint_roundtrip_and_gc(trained):
 
 def test_checkpoint_elastic_reshard(trained):
     """Elastic restore: save unsharded, restore onto an explicit 1-device
-    mesh sharding (the k-device case is covered by the subprocess test).
-    ``make_host_mesh`` goes through the mesh compat shim, so this runs on
-    the pinned jax 0.4.x line too (it xfailed since the seed)."""
+    mesh sharding (the k-device case is covered by the subprocess test)."""
     cfg, tcfg, state, *_ = trained
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.launch.mesh import make_host_mesh
