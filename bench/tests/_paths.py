@@ -1,0 +1,9 @@
+"""Puts the engine's ``src`` and the benchmark's own modules on the path."""
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (os.path.join(ROOT, "src"), BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
