@@ -514,7 +514,7 @@ def _expr_cause(expr, zl, zh, table) -> str:
     """The predicate bound responsible for a refuted expression — called
     only after ``_maybe_any(expr, ...)`` returned False, so every branch
     may assume its subtree is (or contains) a proof. The rendering feeds
-    zone-map telemetry instants, ``last_stats['pruned_by']`` and
+    ``last_verdicts``, ``last_stats['pruned_by']`` and
     ``explain_analyze`` (DESIGN.md §14)."""
     if isinstance(expr, Pred):
         if expr.col in zl and zl[expr.col] > zh[expr.col]:
@@ -611,12 +611,15 @@ def base_masked_program(inner, on_trace=None):
     mask's ``nrows`` comes from the columns' static metadata (every
     encoding carries it). ``on_trace`` fires only when jit (re)traces the
     wrapper — the retrace observability hook both ``PartitionedQuery``
-    and the serving layer's plan cache (core/serve.py) hang counters on.
+    and the serving layer's plan cache (core/serve.py) hang counters on;
+    every trace also bumps the always-on ``programs_traced`` counter.
     """
 
     def wrapped(columns, key_sets, rows):
+        # body runs only when jit (re)traces
+        telemetry.add_counter("programs_traced")
         if on_trace is not None:
-            on_trace()  # body runs only when jit (re)traces
+            on_trace()
         nrows = next(iter(columns.values())).nrows
         base = make_rle_mask([0], [rows - 1], nrows=nrows, capacity=1)
         return inner(columns, key_sets, base)
@@ -791,6 +794,12 @@ class PartitionedQuery(Query):
         return "\n".join(lines)
 
     def run(self, jit: bool = True):
+        """Stream the query over the table's partitions; with tracing on,
+        the ``query`` span is the root of every span the run records."""
+        with telemetry.span("query", qid=self.qid):
+            return self._run(jit)
+
+    def _run(self, jit: bool):
         terminal = self.terminal_op()
         oop = self.order_op()
         if terminal is None and oop is None:
@@ -800,22 +809,23 @@ class PartitionedQuery(Query):
                 "materialize a filter result)")
         # preparation FIRST: join prep records host_keys on each _JoinOp,
         # which partition_can_match's FK zone-map pushdown reads below
-        key_sets = tuple(self._prepare_inputs())
+        with telemetry.span("prepare", qid=self.qid):
+            key_sets = tuple(self._prepare_inputs())
         execute = self._make_executor(jit)
 
         ptable: PartitionedTable = self.table
         todo = []
         pruned_by: Dict[str, int] = {}
         self.last_verdicts = []
-        for i, p in enumerate(ptable.partitions):
-            ok, cause = partition_match_verdict(p, self.ops, ptable)
-            self.last_verdicts.append((i, ok, cause))
-            telemetry.instant("zone_map", "main", qid=self.qid, part=i,
-                              verdict="visit" if ok else "skip", cause=cause)
-            if ok:
-                todo.append(p)
-            else:
-                pruned_by[cause] = pruned_by.get(cause, 0) + 1
+        with telemetry.span("prune", qid=self.qid) as sp:
+            for i, p in enumerate(ptable.partitions):
+                ok, cause = partition_match_verdict(p, self.ops, ptable)
+                self.last_verdicts.append((i, ok, cause))
+                if ok:
+                    todo.append(p)
+                else:
+                    pruned_by[cause] = pruned_by.get(cause, 0) + 1
+            sp.set(visit=len(todo), skip=len(ptable.partitions) - len(todo))
         self.last_stats = {
             "partitions": len(ptable.partitions),
             "executed": len(todo),
@@ -823,8 +833,8 @@ class PartitionedQuery(Query):
             "pruned_by": pruned_by,
         }
         depth, stats = self._depth_and_stats(ptable)
-        # trace spans name partitions by their ingest index, matching the
-        # zone_map verdict instants above
+        # trace spans name partitions by their ingest index, matching
+        # ``last_verdicts`` above
         pidx = {id(p): i for i, p in enumerate(ptable.partitions)}
 
         def label_of(p):
@@ -856,8 +866,9 @@ class PartitionedQuery(Query):
                 # terminal errors still report the partial pipeline stats
                 # (stage ms, retries, degradations — DESIGN.md §15)
                 self.last_stats.update(stats.as_dict())
-            return plan_mod.finalize_scalar_partials(
-                acc, terminal.specs, col_dtypes=ptable.col_dtypes)
+            with telemetry.span("finalize", qid=self.qid):
+                return plan_mod.finalize_scalar_partials(
+                    acc, terminal.specs, col_dtypes=ptable.col_dtypes)
 
         group_names = list(terminal.group)
         partial_specs, _ = plan_mod.decompose_specs(terminal.specs)
@@ -873,13 +884,14 @@ class PartitionedQuery(Query):
                                         label_of=label_of)
         finally:
             self.last_stats.update(stats.as_dict())
-        merged = groupby.finalize_groupby_partials(acc, group_names,
-                                                   terminal.specs)
-        if oop is not None:
-            # groupby + order_by: partials carry PARTIAL aggregates, so
-            # ranking can only happen after the host merge finalizes them
-            merged = order_mod.rank_merged_groupby(merged, oop.by,
-                                                   oop.descending, oop.limit)
+        with telemetry.span("finalize", qid=self.qid):
+            merged = groupby.finalize_groupby_partials(acc, group_names,
+                                                       terminal.specs)
+            if oop is not None:
+                # groupby + order_by: partials carry PARTIAL aggregates, so
+                # ranking can only happen after the host merge finalizes
+                merged = order_mod.rank_merged_groupby(
+                    merged, oop.by, oop.descending, oop.limit)
         return merged
 
     # -- ranked (ORDER BY / TOP-K) execution --------------------------------
@@ -964,11 +976,12 @@ class PartitionedQuery(Query):
         self.last_stats["ranked_skipped"] = ranked_skipped
         self.last_stats["prefetch_wasted"] = wasted
         self.last_stats.update(stats.as_dict())
-        if state is None:  # every partition pruned: empty ranked result
-            names = plan_mod._order_output_cols(self.ops, ptable) or ()
-            state = {"positions": np.zeros((0,), np.int64),
-                     "columns": {n: np.zeros(
-                         (0,), ptable.col_dtypes.get(n, np.float32))
-                         for n in names}}
-        return order_mod.ranked_table_from_state(
-            state, self._ranked_dictionaries())
+        with telemetry.span("finalize", qid=self.qid):
+            if state is None:  # every partition pruned: empty ranked result
+                names = plan_mod._order_output_cols(self.ops, ptable) or ()
+                state = {"positions": np.zeros((0,), np.int64),
+                         "columns": {n: np.zeros(
+                             (0,), ptable.col_dtypes.get(n, np.float32))
+                             for n in names}}
+            return order_mod.ranked_table_from_state(
+                state, self._ranked_dictionaries())

@@ -571,13 +571,8 @@ class QueryServer:
         ticket.plan_hit = hit
         telemetry.instant("serve.plan", qid=q.qid, ticket=ticket.qid,
                           hit=hit)
-        todo = []
-        for i, p in enumerate(self.table.partitions):
-            ok, cause = partition_match_verdict(p, q.ops, self.table)
-            telemetry.instant("zone_map", qid=q.qid, part=i,
-                              verdict="visit" if ok else "skip", cause=cause)
-            if ok:
-                todo.append((i, p))
+        todo = [(i, p) for i, p in enumerate(self.table.partitions)
+                if partition_match_verdict(p, q.ops, self.table)[0]]
         item = _Prepped(ticket, key_sets, entry, hit, todo, q.terminal_op(),
                         q.order_op())
         # served spans are tagged with the QUERY's process-unique qid (the

@@ -42,12 +42,13 @@ depth 0/1/4 equality across all six encodings). Stage wall times are
 recorded per run (``StreamStats``): ``h2d_ms`` / ``compute_ms`` /
 ``merge_ms`` are MAIN-thread wall time spent waiting on transfers,
 dispatching + waiting on device programs, and folding partials
-respectively — a fully hidden transfer shows up as ``h2d_ms ~ 0``, and
-under overlap the three need not sum to the elapsed wall time. With
-tracing enabled (``REPRO_TRACE``, DESIGN.md §14) every stage interval is
-ALSO recorded as a telemetry span — ``emit_stage`` folds the stat and the
-span from the same timestamp pair, so ``StreamStats`` and the Chrome
-trace reconcile by construction.
+respectively (``d2h_ms``, the part of ``merge_ms`` spent fetching each
+partial to the host in one ``jax.device_get``) — a fully hidden transfer
+shows up as ``h2d_ms ~ 0``, and under overlap the three need not sum to
+the elapsed wall time. Each stage is a scoped region (``_Stages``); with
+tracing enabled (``REPRO_TRACE``, DESIGN.md §14) it is a telemetry span
+that feeds its stat from its own timestamp pair, so ``StreamStats``, the
+ring and the profiler's ``repro:`` annotations reconcile by construction.
 
 Fault tolerance (DESIGN.md §15): both drivers probe the fault-injection
 harness (``faults.maybe_inject``) at their three per-partition stages,
@@ -84,7 +85,8 @@ class StreamStats:
     prefetch_depth: int = 0  # effective (post-clamp) depth this run used
     h2d_ms: float = 0.0  # main-thread wait on transfers (hidden -> ~0)
     compute_ms: float = 0.0  # dispatching programs + blocking on partials
-    merge_ms: float = 0.0  # folding partials on the host
+    merge_ms: float = 0.0  # folding partials on the host, d2h_ms included
+    d2h_ms: float = 0.0  # fetching partials to the host (jax.device_get)
     inflight_bytes_max: int = 0  # peak bytes transferred-but-not-yet-folded
     transferred: int = 0  # device_put calls issued
     executed: int = 0  # device programs dispatched
@@ -124,19 +126,114 @@ _EMPTY: dict = {}
 def emit_stage(tel, stats: StreamStats, field: Optional[str], name: str,
                t0: float, t1: float, track: str = "main",
                attrs: dict = _EMPTY) -> None:
-    """Fold one stage interval into ``stats`` AND record it as a span.
+    """Fold one stage interval, timed by the caller, into ``stats`` AND
+    record it as a ring span (the serving layer's per-subscriber spans).
 
-    The ``StreamStats`` a run reports and the spans in its trace come from
-    the SAME timestamp pairs, so ``explain_analyze`` / bench JSONs and the
-    Chrome trace reconcile by construction. ``tel`` is the resolved
-    registry or None (tracing disabled — only the stats add happens);
-    ``field=None`` records a span with no stats counterpart (the device
-    track's dispatch->retire window, already counted via its halves).
-    """
+    ``tel`` is the resolved registry or None (tracing disabled — only the
+    stats add happens); ``field=None`` records a span with no stats
+    counterpart."""
     if field is not None:
         setattr(stats, field, getattr(stats, field) + (t1 - t0) * 1e3)
     if tel is not None:
         tel.record(name, t0, t1 - t0, track, qid=stats.qid, **attrs)
+
+
+class _Clock:
+    """Untraced stage region: adds its wall milliseconds to one
+    ``StreamStats`` field. One per field per executor pass, reused by every
+    partition, so the untraced path allocates no span and takes no lock."""
+
+    __slots__ = ("stats", "field", "t0")
+
+    def __init__(self, stats: StreamStats, field: str):
+        self.stats = stats
+        self.field = field
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        st = self.stats
+        setattr(st, self.field, getattr(st, self.field)
+                + (time.perf_counter() - self.t0) * 1e3)
+        return False
+
+
+_STAGE_FIELDS = ("h2d_ms", "compute_ms", "merge_ms", "d2h_ms")
+
+
+class _Stages:
+    """The scoped stage regions of one executor pass.
+
+    ``stage(field, name, attrs)`` is a ``with`` region. Traced, it is a
+    telemetry span (ring event + ``repro:<name>`` profiler annotation,
+    child of the span open on the thread) that adds its milliseconds to
+    ``stats.<field>`` from its own timestamp pair; untraced, a reused
+    ``_Clock`` adds the same milliseconds and nothing else."""
+
+    def __init__(self, tel, stats: StreamStats):
+        self.tel = tel
+        self.stats = stats
+        self.clocks = ({f: _Clock(stats, f) for f in _STAGE_FIELDS}
+                       if tel is None else None)
+        # the transfer thread's spans name the query's root explicitly
+        self.root = telemetry.current_span_id() if tel is not None else None
+
+    def __call__(self, field: str, name: str, attrs: dict = _EMPTY,
+                 track: str = "main"):
+        if self.tel is None:
+            return self.clocks[field]
+        return self.tel.span(name, track, sink=(self.stats, field),
+                             qid=self.stats.qid, **attrs)
+
+    def issue(self):
+        """The client handing partitions to the transfer thread, and
+        retiring the thread at the end of the pass. On a fresh pool the
+        first hand-off starts the thread, which takes the interpreter for
+        its first copy while the client waits for it back. No stats field:
+        it is not a wait on a transfer's completion."""
+        if self.tel is None:
+            return telemetry.NULL_SPAN
+        return self.tel.span("h2d_issue", qid=self.stats.qid)
+
+    def transfer(self, attrs: dict):
+        """The copy-issue window on the transfer thread (no stats field:
+        the main thread's wait is ``h2d_wait``)."""
+        return self.tel.span("transfer", "transfer", parent=self.root,
+                             qid=self.stats.qid, **attrs)
+
+    def fold(self, fold: Callable, acc, item, partial, part, attrs: dict):
+        """``fold(acc, item, partial)`` as the ``fold`` stage. The partial
+        comes to the host first in ONE ``jax.device_get`` (every leaf's
+        copy issued before any is awaited), the ``d2h`` child stage."""
+        with self("merge_ms", "fold", attrs):
+            faults.maybe_inject("fold", part)
+            with self("d2h_ms", "d2h", attrs):
+                host = jax.device_get(partial)
+            return fold(acc, item, host)
+
+    def program(self, disp, blk, attrs: dict) -> None:
+        """The program's dispatch->retire window on the device track: from
+        the end of its ``dispatch`` to the end of its ``block``. Ring only
+        (its halves are the annotated, stat-feeding stages)."""
+        if self.tel is not None:
+            self.tel.record("program", disp.t1, blk.t1 - disp.t1, "device",
+                            qid=self.stats.qid, **attrs)
+
+
+class _TransferPool(ThreadPoolExecutor):
+    """The one transfer thread of a executor pass. Its shutdown on leaving
+    the ``with`` is client time on the thread's account, so it runs as an
+    ``h2d_issue`` stage, like the hand-off that started it."""
+
+    def __init__(self, stage: _Stages):
+        super().__init__(max_workers=1)
+        self._stage = stage
+
+    def __exit__(self, *exc):
+        with self._stage.issue():
+            return super().__exit__(*exc)
 
 
 def clamp_depth(depth: int, max_part_nbytes: int,
@@ -291,28 +388,22 @@ def _fold_pipeline(items, start, acc, transfer, compute, fold, depth,
     def xfer(i):
         return _transfer_with_retry(transfer, items[i], part_of(i), stats)
 
+    stage = _Stages(tel, stats)
+
     if depth <= 0:
         i = start
         try:
             while i < len(items):
                 item = items[i]
                 a = attr(item)
-                t0 = time.perf_counter()
-                cols = xfer(i)
-                _block(cols)
-                t1 = time.perf_counter()
-                emit_stage(tel, stats, "h2d_ms", "transfer", t0, t1,
-                           "transfer", a)
-                faults.maybe_inject("compute", part_of(i))
-                partial = compute(item, cols)
-                _block(partial)
-                t2 = time.perf_counter()
-                emit_stage(tel, stats, "compute_ms", "program", t1, t2,
-                           "device", a)
-                faults.maybe_inject("fold", part_of(i))
-                acc = fold(acc, item, partial)
-                t3 = time.perf_counter()
-                emit_stage(tel, stats, "merge_ms", "fold", t2, t3, "main", a)
+                with stage("h2d_ms", "transfer", a, "transfer"):
+                    cols = xfer(i)
+                    _block(cols)
+                with stage("compute_ms", "program", a, "device"):
+                    faults.maybe_inject("compute", part_of(i))
+                    partial = compute(item, cols)
+                    _block(partial)
+                acc = stage.fold(fold, acc, item, partial, part_of(i), a)
                 stats.transferred += 1
                 stats.executed += 1
                 if nbytes_of is not None:
@@ -335,13 +426,10 @@ def _fold_pipeline(items, start, acc, transfer, compute, fold, depth,
         # there, rendered on the transfer track
         if tel is None:
             return xfer(i)
-        t0 = time.perf_counter()
-        cols = xfer(i)
-        tel.record("transfer", t0, time.perf_counter() - t0, "transfer",
-                   qid=stats.qid, **attr(items[i]))
-        return cols
+        with stage.transfer(attr(items[i])):
+            return xfer(i)
 
-    with ThreadPoolExecutor(max_workers=1) as pool:
+    with _TransferPool(stage) as pool:
         try:
 
             def top_up():
@@ -363,50 +451,36 @@ def _fold_pipeline(items, start, acc, transfer, compute, fold, depth,
             def dispatch_head():
                 i, item, fut = ring.popleft()
                 a = attr(item)
-                t0 = time.perf_counter()
-                cols = fut.result()  # ~0 when the copy hid behind compute
-                t1 = time.perf_counter()
-                emit_stage(tel, stats, "h2d_ms", "h2d_wait", t0, t1,
-                           "main", a)
-                faults.maybe_inject("compute", part_of(i))
-                partial = compute(item, cols)
-                t2 = time.perf_counter()
-                emit_stage(tel, stats, "compute_ms", "dispatch", t1, t2,
-                           "main", a)
+                with stage("h2d_ms", "h2d_wait", a):
+                    cols = fut.result()  # ~0 when the copy hid behind compute
+                with stage("compute_ms", "dispatch", a) as disp:
+                    faults.maybe_inject("compute", part_of(i))
+                    partial = compute(item, cols)
                 stats.executed += 1
-                return i, item, partial, t2
+                return i, item, partial, disp
 
-            top_up()
+            with stage.issue():
+                top_up()
             if ring:
                 pending = dispatch_head()
             while pending is not None:
-                i, item, partial, t_disp = pending
+                i, item, partial, disp = pending
                 head = i  # acc covers items[start:i]
                 a = attr(item)
-                t0 = time.perf_counter()
-                _block(partial)  # the device is the gate
-                t1 = time.perf_counter()
-                emit_stage(tel, stats, "compute_ms", "block", t0, t1,
-                           "main", a)
-                # the program's dispatch->retire window on the device
-                # track; its halves already fed compute_ms, no stats field
-                emit_stage(tel, stats, None, "program", t_disp, t1,
-                           "device", a)
+                with stage("compute_ms", "block", a) as blk:
+                    _block(partial)  # the device is the gate
+                stage.program(disp, blk, a)
                 # program ``i`` retired: launch ``i+1`` BEFORE folding
                 # ``i`` so the fold runs under the next program
                 pending = dispatch_head() if ring else None
-                t1 = time.perf_counter()
-                faults.maybe_inject("fold", part_of(i))
-                acc = fold(acc, item, partial)
-                t2 = time.perf_counter()
-                emit_stage(tel, stats, "merge_ms", "fold", t1, t2,
-                           "main", a)
+                acc = stage.fold(fold, acc, item, partial, part_of(i), a)
                 head = i + 1
                 if nbytes_of is not None:
                     inflight -= nbytes_of(item)
                 # the fold head advanced: replenish the transfer ring
                 # (copies run on the worker while the next program runs)
-                top_up()
+                with stage.issue():
+                    top_up()
         except DeviceOOMError as exc:
             raise _Restart(exc, acc, head) from None
         finally:
@@ -486,40 +560,42 @@ def _ranked_pipeline(items, start, state, transfer, compute, fold, prune,
             return _EMPTY
         return {"part": label_of(item)}
 
+    stage = _Stages(tel, stats)
+
     def do_transfer(i):
         if tel is None:
             return _transfer_with_retry(transfer, items[i], part_of(i),
                                         stats)
-        t0 = time.perf_counter()
-        cols = _transfer_with_retry(transfer, items[i], part_of(i), stats)
-        tel.record("transfer", t0, time.perf_counter() - t0, "transfer",
-                   qid=stats.qid, **attr(items[i]))
-        return cols
+        with stage.transfer(attr(items[i])):
+            return _transfer_with_retry(transfer, items[i], part_of(i),
+                                        stats)
 
     ring: deque = deque()  # (pos, item, future cols): not yet bound-gated
     idx = start
     head = start
     inflight = 0
-    with ThreadPoolExecutor(max_workers=1) as pool:
+    with _TransferPool(stage) as pool:
         try:
             while idx < len(items) or ring:
-                while len(ring) < depth + 1 and idx < len(items):
-                    i, item = idx, items[idx]
-                    idx += 1
-                    if prune(state, item):
-                        decisions[i] = "issue"
-                        if tel is not None:
-                            tel.instant("ranked_prune", "main",
-                                        qid=stats.qid, stage="issue",
-                                        **attr(item))
-                        continue
-                    # speculative, off-thread: bytes at risk, not results
-                    ring.append((i, item, pool.submit(do_transfer, i)))
-                    stats.transferred += 1
-                    if nbytes_of is not None:
-                        inflight += nbytes_of(item)
-                        stats.inflight_bytes_max = max(
-                            stats.inflight_bytes_max, inflight)
+                with stage.issue():
+                    while len(ring) < depth + 1 and idx < len(items):
+                        i, item = idx, items[idx]
+                        idx += 1
+                        if prune(state, item):
+                            decisions[i] = "issue"
+                            if tel is not None:
+                                tel.instant("ranked_prune", "main",
+                                            qid=stats.qid, stage="issue",
+                                            **attr(item))
+                            continue
+                        # speculative, off-thread: bytes at risk, not
+                        # results
+                        ring.append((i, item, pool.submit(do_transfer, i)))
+                        stats.transferred += 1
+                        if nbytes_of is not None:
+                            inflight += nbytes_of(item)
+                            stats.inflight_bytes_max = max(
+                                stats.inflight_bytes_max, inflight)
                 if not ring:
                     break
                 i, item, fut = ring.popleft()
@@ -535,22 +611,15 @@ def _ranked_pipeline(items, start, state, transfer, compute, fold, prune,
                     fut.cancel()  # un-started copies are dropped entirely
                     continue
                 a = attr(item)
-                t0 = time.perf_counter()
-                cols = fut.result()
-                t1 = time.perf_counter()
-                emit_stage(tel, stats, "h2d_ms", "h2d_wait", t0, t1,
-                           "main", a)
-                faults.maybe_inject("compute", part_of(i))
-                partial = compute(item, cols)  # gated: pruned never run
-                _block(partial)
-                t2 = time.perf_counter()
-                emit_stage(tel, stats, "compute_ms", "program", t1, t2,
-                           "device", a)
-                faults.maybe_inject("fold", part_of(i))
-                state = fold(state, item, partial)
-                t3 = time.perf_counter()
-                emit_stage(tel, stats, "merge_ms", "fold", t2, t3,
-                           "main", a)
+                with stage("h2d_ms", "h2d_wait", a):
+                    cols = fut.result()
+                with stage("compute_ms", "dispatch", a) as disp:
+                    faults.maybe_inject("compute", part_of(i))
+                    partial = compute(item, cols)  # gated: pruned never run
+                with stage("compute_ms", "block", a) as blk:
+                    _block(partial)
+                stage.program(disp, blk, a)
+                state = stage.fold(fold, state, item, partial, part_of(i), a)
                 stats.executed += 1
                 decisions[i] = "exec"
         except DeviceOOMError as exc:

@@ -14,6 +14,13 @@ planner choose that path?" end to end:
     a worker thread but renders on the transfer track, and the
     dispatch->retire window of each device program renders on the device
     track (DESIGN.md §12's three overlappable stages, one track each).
+    Every event carries an ``id`` and the ``parent`` id of the span that
+    caused it: by default the innermost span open on the recording
+    thread, or one named explicitly (the transfer thread's copies name
+    their query's root span). For its extent a span also opens
+    ``jax.profiler.TraceAnnotation("repro:<name>", **attrs)``, so under a
+    running profiler the engine's stages land in the same ``.xplane.pb``
+    as the device's operations, on the profiler's own clock.
 
   * monotonic counters — ``add_counter`` / ``counter``. The H2D transfer
     counters (``h2d_calls`` / ``h2d_bytes``) are ALWAYS on, enabled or
@@ -32,12 +39,13 @@ planner choose that path?" end to end:
 Enablement & cost: recording is gated on
 ``DispatchPolicy.enable_trace`` (env ``REPRO_TRACE``, default off).
 Disabled, ``span()`` returns a shared no-op context manager after one
-policy-field read — no allocation, no lock, no timestamps — and the only
-always-on work is the two integer adds of ``record_h2d`` per PARTITION
-transfer (micro- to milliseconds of device work each). The stream bench
-CI-gates the disabled-path overhead at <2% of end-to-end wall time.
-The ring buffer holds ``DispatchPolicy.trace_buffer_events`` events
-(env ``REPRO_TRACE_BUFFER``); beyond that the OLDEST events drop (the
+policy-field read — no allocation, no lock, no timestamps, no
+annotation — and the only always-on work is the two integer adds of
+``record_h2d`` per PARTITION transfer (micro- to milliseconds of device
+work each) and the ``programs_traced`` add each time jit traces a
+partition program. The ring buffer holds
+``DispatchPolicy.trace_buffer_events`` events (env
+``REPRO_TRACE_BUFFER``); beyond that the OLDEST events drop (the
 ``dropped_events`` counter says how many), so tracing a long-running
 server is bounded-memory by construction.
 
@@ -55,6 +63,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # Logical stage tracks (chrome-trace rows), in render order. Spans may
 # name other tracks; they get rows after these. ``fault`` carries the
 # resilience events (injections, retries, degradations, cancellations —
@@ -63,6 +73,9 @@ from typing import Callable, Dict, List, Optional
 TRACKS = ("main", "transfer", "device", "fault")
 
 _DEFAULT_BUFFER = 1 << 16
+
+# profiler annotations are named ``repro:<span name>``
+ANNOTATION_PREFIX = "repro:"
 
 
 def _policy():
@@ -84,6 +97,31 @@ def buffer_limit() -> int:
     return lim if lim and lim > 0 else _DEFAULT_BUFFER
 
 
+# ``parent`` when none is named: the innermost span open on the recording
+# thread (each thread keeps its own stack of open spans)
+_CURRENT = object()
+_IDS = itertools.count(1)
+_open = threading.local()
+
+
+def _stack() -> list:
+    stack = getattr(_open, "stack", None)
+    if stack is None:
+        stack = _open.stack = []
+    return stack
+
+
+def current_span():
+    """The innermost recording span open on this thread, or None."""
+    stack = getattr(_open, "stack", None)
+    return stack[-1] if stack else None
+
+
+def current_span_id() -> Optional[int]:
+    sp = current_span()
+    return sp.id if sp is not None else None
+
+
 class Telemetry:
     """Thread-safe span/counter registry with a bounded event ring."""
 
@@ -97,10 +135,19 @@ class Telemetry:
     # -- events -------------------------------------------------------------
 
     def record(self, name: str, t0: float, dur: float, track: str = "main",
+               *, span_id: Optional[int] = None, parent=_CURRENT,
                **attrs) -> None:
-        """Append one complete span (``t0``/``dur`` in perf_counter secs)."""
+        """Append one complete span (``t0``/``dur`` in perf_counter secs).
+
+        ``parent`` defaults to the innermost span open on this thread;
+        ``span_id`` to a fresh id (a scoped span passes the one it handed
+        its children)."""
+        if parent is _CURRENT:
+            parent = current_span_id()
         ev = {"name": name, "track": track, "ts": t0, "dur": dur,
-              "attrs": attrs}
+              "attrs": attrs,
+              "id": next(_IDS) if span_id is None else span_id,
+              "parent": parent}
         limit = buffer_limit()
         with self._lock:
             self._events.append(ev)
@@ -128,6 +175,14 @@ class Telemetry:
     def query_trace(self, qid: int) -> List[dict]:
         """Every recorded event attributed to query ``qid``."""
         return self.events(qid=qid)
+
+    def span(self, name: str, track: str = "main", *, parent=_CURRENT,
+             sink=None, **attrs) -> "_Span":
+        """A recording span, whatever the policy says (``span()`` below is
+        the policy-gated entry point). ``sink=(obj, field)`` also adds the
+        span's milliseconds to ``obj.field`` from the span's own
+        timestamp pair, so a stat and its span cannot disagree."""
+        return _Span(self, name, track, attrs, parent, sink)
 
     # -- counters -----------------------------------------------------------
 
@@ -229,22 +284,52 @@ def query_trace(qid: int) -> List[dict]:
 
 
 class _Span:
-    """Recording span: measures wall time between __enter__/__exit__."""
+    """Recording span: measures wall time between __enter__/__exit__, and
+    over the same extent holds a ``repro:<name>`` profiler annotation."""
 
-    __slots__ = ("name", "track", "attrs", "t0")
+    __slots__ = ("reg", "name", "track", "attrs", "parent", "sink", "id",
+                 "t0", "t1", "_ann")
 
-    def __init__(self, name, track, attrs):
+    def __init__(self, reg, name, track, attrs, parent=_CURRENT, sink=None):
+        self.reg = reg
         self.name = name
         self.track = track
         self.attrs = attrs
+        self.parent = parent
+        self.sink = sink
+
+    def set(self, **attrs) -> None:
+        """Attach attributes known only once the span's work has run."""
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
 
     def __enter__(self):
+        stack = _stack()
+        if self.parent is _CURRENT:
+            self.parent = stack[-1].id if stack else None
+        self.id = next(_IDS)
+        stack.append(self)
+        self._ann = TraceAnnotation(
+            ANNOTATION_PREFIX + self.name,
+            **{k: v for k, v in self.attrs.items() if v is not None})
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        _REGISTRY.record(self.name, self.t0, time.perf_counter() - self.t0,
-                         self.track, **self.attrs)
+        self.t1 = t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        stack = _stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        else:  # closed out of order: drop it wherever it sits
+            stack.remove(self)
+        dur = t1 - self.t0
+        if self.sink is not None:
+            obj, field = self.sink
+            setattr(obj, field, getattr(obj, field) + dur * 1e3)
+        self.reg.record(self.name, self.t0, dur, self.track,
+                        span_id=self.id, parent=self.parent, **self.attrs)
         return False
 
 
@@ -253,6 +338,9 @@ class _NullSpan:
 
     __slots__ = ()
 
+    def set(self, **attrs) -> None:
+        pass
+
     def __enter__(self):
         return self
 
@@ -260,14 +348,14 @@ class _NullSpan:
         return False
 
 
-_NULL_SPAN = _NullSpan()
+NULL_SPAN = _NullSpan()
 
 
 def span(name: str, track: str = "main", **attrs):
     """Span context manager; the shared no-op when tracing is disabled."""
     if not enabled():
-        return _NULL_SPAN
-    return _Span(name, track, attrs)
+        return NULL_SPAN
+    return _Span(_REGISTRY, name, track, attrs)
 
 
 def instant(name: str, track: str = "main", **attrs) -> None:
@@ -293,14 +381,18 @@ def add_counter(name: str, value: float = 1) -> None:
 _h2d_listeners: List[Callable] = []
 
 
-def record_h2d(nbytes: int, tree=None, qid: Optional[int] = None) -> None:
-    """Book one host->device partition transfer of ``nbytes`` bytes."""
+def record_h2d(nbytes: int, tree=None) -> None:
+    """Book one host->device partition transfer of ``nbytes`` bytes; with
+    tracing on, the bytes also become the ``bytes`` attr of the span open
+    on this thread (the executor's ``transfer`` span)."""
     _REGISTRY.add_counter("h2d_calls", 1)
     _REGISTRY.add_counter("h2d_bytes", nbytes)
     for fn in list(_h2d_listeners):
         fn(nbytes, tree)
     if enabled():
-        _REGISTRY.instant("h2d", track="transfer", bytes=nbytes, qid=qid)
+        sp = current_span()
+        if sp is not None:
+            sp.set(bytes=sp.attrs.get("bytes", 0) + nbytes)
 
 
 # ---------------------------------------------------------------------------

@@ -76,6 +76,7 @@ def bucketize_kernel(boundaries: jax.Array, queries: jax.Array, right: bool = Tr
         ],
         out_specs=pl.BlockSpec((Q_TILE,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((q_pad,), jnp.int32),
+        name="bucketize_kernel",
         interpret=interpret,
     )(boundaries, queries)
     return out[:n_q]
@@ -117,6 +118,7 @@ def bucketize_count_kernel(boundaries: jax.Array, queries: jax.Array,
         ],
         out_specs=pl.BlockSpec((Q_TILE,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((q_pad,), jnp.int32),
+        name="bucketize_count_kernel",
         interpret=interpret,
     )(boundaries, queries)
     return out[:n_q]

@@ -57,6 +57,7 @@ def rle_decode_kernel(values: jax.Array, starts: jax.Array, ends: jax.Array,
         ],
         out_specs=pl.BlockSpec((ROW_TILE,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((rows_pad,), values.dtype),
+        name="rle_decode_kernel",
         interpret=interpret,
     )(values, starts, ends, n_arr)
     return out[:nrows]

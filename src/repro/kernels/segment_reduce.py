@@ -70,6 +70,7 @@ def segment_sum_kernel(values: jax.Array, segment_ids: jax.Array,
         out_specs=pl.BlockSpec((num_segments,), lambda i: (0,)),
         out_shape=jax.ShapeDtypeStruct((num_segments,), jnp.float32),
         scratch_shapes=[pltpu.VMEM((num_segments,), jnp.float32)],
+        name="segment_sum_kernel",
         interpret=interpret,
     )(values, segment_ids)
     return out

@@ -139,6 +139,7 @@ def topk_kernel(values: jax.Array, k: int, interpret: bool = False):
                    pl.BlockSpec((1, K), lambda t: (0, t))],
         out_shape=[jax.ShapeDtypeStruct((1, n_tiles * K), values.dtype),
                    jax.ShapeDtypeStruct((1, n_tiles * K), jnp.int32)],
+        name="topk_kernel",
         interpret=interpret,
     )(values.reshape(pad // K, K))
     vals, idx = vals[0], idx[0]

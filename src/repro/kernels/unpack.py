@@ -122,6 +122,7 @@ def unpack_kernel(words: jax.Array, bit_width: int, offset, nvals: int,
         ],
         out_specs=pl.BlockSpec((ROW_TILE, ROW_VALS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, ROW_VALS), jnp.int32),
+        name="unpack_kernel",
         interpret=interpret,
     )(words.reshape(rows_pad, 4 * b).T, off_arr)
     return out.reshape(-1)[:nvals]
@@ -162,6 +163,7 @@ def bucketize_packed_kernel(boundaries: jax.Array, words: jax.Array,
         ],
         out_specs=pl.BlockSpec((VAL_TILE,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.int32),
+        name="bucketize_packed_kernel",
         interpret=interpret,
     )(boundaries, words, off_arr)
     return out[:nvals]
@@ -212,6 +214,7 @@ def rle_decode_packed_kernel(words: jax.Array, bit_width: int, offset,
         ],
         out_specs=pl.BlockSpec((VAL_TILE,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((rows_pad,), jnp.int32),
+        name="rle_decode_packed_kernel",
         interpret=interpret,
     )(words, starts, ends, n_arr, off_arr)
     return out[:nrows]

@@ -16,15 +16,24 @@ Five layers:
      responsible predicate bound, dispatch routing records, and the
      always-on H2D counters behind ``count_h2d`` / ``transfer_counter``;
   5. concurrency + cost — traced concurrent serving reconciles per-query
-     attribution with ticket stats, and trace-ON wall stays within a few
-     percent of trace-OFF on the streamed path (the bench CI-gates 2%;
-     the in-suite guard is looser to absorb runner noise).
+     attribution with ticket stats, and what tracing costs is counted:
+     untraced, no event and no annotation; traced, a fixed number of
+     events per executed partition;
+  6. the profiler's clock — under ``jax.profiler`` the engine's stages
+     land as ``repro:`` host annotations beside the device's operations,
+     nested as they ran, with the same durations as ``StreamStats``.
 """
+import collections
 import dataclasses
+import glob
 import json
+import os
+import sys
 import threading
 
+import jax
 import numpy as np
+import pytest
 
 from repro.core import compress, stream, telemetry
 from repro.core.partition import (
@@ -105,7 +114,7 @@ def test_chrome_trace_export(tmp_path):
     with dispatch.overrides(enable_trace=True):
         with telemetry.span("work", "device", qid=1):
             pass
-        telemetry.instant("h2d", "transfer", bytes=64, skipped=None)
+        telemetry.instant("mark", "transfer", bytes=64, skipped=None)
     path = telemetry.export_chrome_trace(str(tmp_path / "trace.json"))
     with open(path) as f:
         doc = json.load(f)
@@ -221,17 +230,25 @@ def test_streamed_run_emits_qid_tagged_spans(rng):
         q.run()
     tr = telemetry.query_trace(q.qid)
     names = {e["name"] for e in tr}
-    assert {"transfer", "program", "fold", "zone_map"} <= names
+    assert {"query", "prepare", "prune", "transfer", "program", "fold",
+            "d2h", "finalize"} <= names
     # one program span per executed partition, labelled with its index
     progs = [e for e in tr if e["name"] == "program"]
     assert len(progs) == q.last_stats["executed"]
     assert all(isinstance(e["attrs"].get("part"), int) for e in progs)
-    # zone-map instants: one verdict per partition, skips carry a cause
-    zm = [e for e in tr if e["name"] == "zone_map"]
-    assert len(zm) == len(pt.partitions)
-    skips = [e for e in zm if e["attrs"]["verdict"] == "skip"]
-    assert len(skips) == q.last_stats["skipped"] > 0
-    assert all("outside zone" in e["attrs"]["cause"] for e in skips)
+    # each transfer span carries the bytes it shipped
+    xfers = [e for e in tr if e["name"] == "transfer"]
+    assert len(xfers) == q.last_stats["transferred"]
+    assert all(e["attrs"]["bytes"] > 0 for e in xfers)
+    # the prune span counts the verdicts; skips carry a cause
+    (pr,) = [e for e in tr if e["name"] == "prune"]
+    assert pr["attrs"]["visit"] == q.last_stats["executed"]
+    assert pr["attrs"]["skip"] == q.last_stats["skipped"] > 0
+    skips = [v for v in q.last_verdicts if not v[1]]
+    assert len(skips) == q.last_stats["skipped"]
+    assert all("outside zone" in cause for _, _, cause in skips)
+    # the verdicts are no longer one event each
+    assert "zone_map" not in names and "h2d" not in names
 
 
 def test_route_records_mark_compilations(rng):
@@ -329,33 +346,57 @@ def test_traced_concurrent_serving_reconciles(rng):
     assert total_transferred == telemetry.registry().counter("h2d_calls")
 
 
-def test_trace_overhead_within_noise(rng):
-    """Trace-ON wall vs trace-OFF wall on the depth-2 streamed path.
+PER_PARTITION = ("transfer", "h2d_wait", "dispatch", "block", "program",
+                 "fold", "d2h")
+PER_QUERY = ("query", "prepare", "prune", "finalize")
 
-    The enabled path strictly dominates the disabled path (every span
-    site allocates and locks), so this ratio upper-bounds what the
-    default-off instrumentation can cost. The CI bench gates the same
-    ratio at 2% on the quick workload; in-suite the bound is looser
-    (runner noise on a ~tens-of-ms wall) and exists to catch order-of-
-    magnitude regressions (e.g. an eager span on the disabled path)."""
+
+def _counting_annotations(monkeypatch):
+    made = []
+
+    class Counting(telemetry.TraceAnnotation):
+        def __init__(self, name, **kwargs):
+            made.append(name)
+            super().__init__(name, **kwargs)
+
+    monkeypatch.setattr(telemetry, "TraceAnnotation", Counting)
+    return made
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["off", "on"])
+def test_trace_overhead_within_noise(rng, monkeypatch, traced):
+    """What tracing costs on the depth-2 streamed path, counted rather
+    than timed (its wall-clock cost is measured on the chip, PERF.md).
+
+    Off: the run records no ring event, constructs no profiler
+    annotation, and ``span()`` is the shared no-op. On: the ring holds a
+    fixed number of events per executed partition plus a fixed number
+    per query, and every span but the ring-only ``program`` opens one
+    annotation."""
     pt = _clustered_pt(rng, n=60_000, parts=8)
-    q = (PartitionedQuery(pt).filter(col("units") < 90)
-         .groupby(["region"], {"s": ("sum", "qty")}, num_groups_cap=8))
+    q = (PartitionedQuery(pt).filter(col("qty") < 750)
+         .groupby(["region"], {"s": ("sum", "units")}, num_groups_cap=8))
     q.run()  # compile once
-    from benchmarks.common import time_interleaved
-
+    made = _counting_annotations(monkeypatch)
     telemetry.reset()
-
-    def off():
-        with dispatch.overrides(prefetch_depth=2):
-            return q.run()
-
-    def on():
-        with dispatch.overrides(prefetch_depth=2, enable_trace=True):
-            return q.run()
-
-    best = time_interleaved({"off": off, "on": on}, rounds=5, warmup=1)
-    assert best["on"] / best["off"] < 1.25
+    with dispatch.overrides(prefetch_depth=2, enable_trace=traced):
+        assert (telemetry.span("a") is telemetry.span("b")) is not traced
+        q.run()
+    evs = telemetry.registry().events()
+    if not traced:
+        assert evs == [] and made == []
+        return
+    executed = q.last_stats["executed"]
+    assert 0 < executed < len(pt.partitions)
+    counts = collections.Counter(e["name"] for e in evs)
+    expected = dict.fromkeys(PER_PARTITION, executed)
+    expected.update(dict.fromkeys(PER_QUERY, 1))
+    # the ring's fill, a top-up after each fold, the pool's shutdown
+    expected["h2d_issue"] = executed + 2
+    assert counts == expected
+    assert len(made) == len(evs) - executed
+    assert set(made) == {telemetry.ANNOTATION_PREFIX + n
+                         for n in expected if n != "program"}
 
 
 def test_serving_stats_unchanged_when_disabled(rng):
@@ -373,3 +414,119 @@ def test_serving_stats_unchanged_when_disabled(rng):
     np.testing.assert_array_equal(np.asarray(solo["s"]),
                                   np.asarray(served["s"]))
     assert {"executed", "skipped", "transferred"} <= set(t.stats)
+
+
+# ---------------------------------------------------------------------------
+# 6. the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _annotations(logdir):
+    """``repro:`` host events of the one ``.xplane.pb`` under ``logdir``
+    as (name, start_ns, end_ns, thread, stats)."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for j, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith(telemetry.ANNOTATION_PREFIX):
+                    out.append((ev.name[len(telemetry.ANNOTATION_PREFIX):],
+                                ev.start_ns, ev.start_ns + ev.duration_ns,
+                                (plane.name, j), dict(ev.stats)))
+    return out
+
+
+def _profiled_pt(rng, n=400_000, parts=8):
+    """Many groups, so each partition's fold and fetch take long enough
+    (hundreds of microseconds on the CPU) that an annotation's own
+    microsecond stays under 1% of them."""
+    data = {
+        "qty": np.sort(rng.integers(0, 1000, n)).astype(np.int32),
+        "units": rng.integers(0, 100, n).astype(np.int32),
+        "price": rng.random(n).astype(np.float32),
+        "region": rng.integers(0, 5, n).astype(np.int32),
+    }
+    return PartitionedTable.from_arrays(data, cfg=CFG, num_partitions=parts)
+
+
+@pytest.mark.parametrize("terminal", ["groupby", "aggregate"])
+def test_profiler_trace_holds_the_engine_stages(rng, tmp_path, terminal):
+    pt = _profiled_pt(rng)
+    q = PartitionedQuery(pt).filter(col("qty") < 750)
+    if terminal == "groupby":
+        q = q.groupby(["qty", "region"], {"s": ("sum", "units"),
+                                          "p": ("avg", "price")},
+                      num_groups_cap=8192)
+    else:
+        q = q.aggregate({f"{agg}_{c}": (agg, c)
+                         for agg in ("sum", "min", "max", "avg")
+                         for c in ("qty", "units", "price")})
+    q.run()  # compile outside the profiled run
+    telemetry.reset()
+    # a span opens its annotation, then reads the clock; a thread switch
+    # to the transfer thread in between would widen the annotation by up
+    # to a switch interval, so the client keeps the interpreter until it
+    # waits on the device or the transfer thread
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1.0)
+    try:
+        with dispatch.overrides(enable_trace=True, prefetch_depth=2):
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                q.run()
+            finally:
+                jax.profiler.stop_trace()
+    finally:
+        sys.setswitchinterval(interval)
+    st = q.last_stats
+    spans = [s for s in _annotations(str(tmp_path))
+             if s[4].get("qid") == q.qid]
+    (root,) = [s for s in spans if s[0] == "query"]
+    main = [s for s in spans if s[3] == root[3]]
+    assert all(root[1] <= s[1] and s[2] <= root[2] for s in main)
+    names = {s[0] for s in main}
+    assert {"prepare", "prune", "h2d_wait", "dispatch", "block", "fold",
+            "d2h", "finalize"} <= names
+    # the copies run on the transfer thread, not under the client's stages
+    assert {s[0] for s in spans if s[3] != root[3]} == {"transfer"}
+    folds = [s for s in main if s[0] == "fold"]
+    d2hs = [s for s in main if s[0] == "d2h"]
+    assert len(folds) == len(d2hs) == st["executed"] > 0
+    for d in d2hs:  # each fetch inside a fold
+        assert any(f[1] <= d[1] and d[2] <= f[2] for f in folds)
+    (prune,) = [s for s in main if s[0] == "prune"]
+    assert prune[4]["visit"] == st["executed"]
+    assert prune[4]["skip"] == st["skipped"]
+
+    def ms(group):
+        return sum(e - s for _, s, e, _, _ in group) / 1e6
+
+    assert ms(folds) == pytest.approx(st["merge_ms"], rel=0.01)
+    assert ms(d2hs) == pytest.approx(st["d2h_ms"], rel=0.01)
+    # the ring's parent ids form one tree rooted at the query span
+    ring = telemetry.query_trace(q.qid)
+    ids = {e["id"] for e in ring}
+    (top,) = [e for e in ring if e["name"] == "query"]
+    assert top["parent"] is None
+    assert all(e["parent"] in ids for e in ring if e is not top)
+    by_id = {e["id"]: e for e in ring}
+    assert all(by_id[e["parent"]]["name"] == "fold"
+               for e in ring if e["name"] == "d2h")
+
+
+def test_programs_traced_counts_jit_traces(rng):
+    pt = _clustered_pt(rng, n=6000, parts=4)
+    q = (PartitionedQuery(pt).filter(col("qty") < 500)
+         .aggregate({"s": ("sum", "units")}))
+    reg = telemetry.registry()
+    before = reg.counter("programs_traced")
+    q.run()  # tracing off: the counter is always on
+    first = reg.counter("programs_traced")
+    assert first > before
+    q.run()
+    assert reg.counter("programs_traced") == first
