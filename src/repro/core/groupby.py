@@ -220,12 +220,11 @@ def grouping(view: SegmentView, group_names: Sequence[str], num_groups_cap: int,
                         else combined * num_groups_cap + inv32)
         _, gid, num_groups = prim.unique_with_inverse(
             combined, view.valid, num_groups_cap)
-    # representative segment per group (first occurrence) for key recovery
+    # representative segment per group (first occurrence) for key recovery;
+    # empty slots read the int32 maximum
     seg_ids = jnp.arange(gid.shape[0], dtype=POS_DTYPE)
-    big = jnp.asarray(jnp.iinfo(jnp.int32).max, POS_DTYPE)
     gid_safe = jnp.where(view.valid, gid, num_groups_cap)
-    rep = jnp.full((num_groups_cap,), big, POS_DTYPE).at[gid_safe].min(
-        seg_ids, mode="drop")
+    rep = dispatch.segment_min(seg_ids, gid_safe, num_groups_cap)
     return gid_safe, num_groups, rep
 
 
@@ -235,8 +234,9 @@ def grouping(view: SegmentView, group_names: Sequence[str], num_groups_cap: int,
 
 
 def _segsum(values, gid, cap):
-    # dispatch-routed (DESIGN.md §5): MXU one-hot matmul kernel when the
-    # policy allows and cap fits a VMEM block, XLA scatter-add otherwise.
+    # dispatch-routed (DESIGN.md §5): integers over a small table reduce
+    # densely on the TPU; float32 takes the MXU one-hot matmul kernel when
+    # the policy allows and cap fits a VMEM block; XLA scatter-add otherwise.
     return dispatch.segment_sum(values, gid, cap)
 
 
@@ -256,13 +256,11 @@ def aggregate(view: SegmentView, gid: jax.Array, specs, num_groups_cap: int):
             out[out_name] = _segsum(
                 v.astype(f32) * lengths.astype(f32), gid, num_groups_cap)
         elif agg == "min":
-            init = jnp.full((num_groups_cap,), jnp.inf, f32)
             vv = jnp.where(view.valid, v.astype(f32), jnp.inf)
-            out[out_name] = init.at[gid].min(vv, mode="drop")
+            out[out_name] = dispatch.segment_min(vv, gid, num_groups_cap)
         elif agg == "max":
-            init = jnp.full((num_groups_cap,), -jnp.inf, f32)
             vv = jnp.where(view.valid, v.astype(f32), -jnp.inf)
-            out[out_name] = init.at[gid].max(vv, mode="drop")
+            out[out_name] = dispatch.segment_max(vv, gid, num_groups_cap)
         elif agg in ("avg", "var", "std"):
             s = _segsum(v.astype(f32) * lengths.astype(f32), gid, num_groups_cap)
             c = _segsum(lengths.astype(f32), gid, num_groups_cap)
@@ -277,6 +275,20 @@ def aggregate(view: SegmentView, gid: jax.Array, specs, num_groups_cap: int):
         else:
             raise ValueError(f"unknown agg {agg}")
     return out
+
+
+def _gid_per_row(view: SegmentView, gid: jax.Array, nrows: int) -> jax.Array:
+    """Group id of the segment covering or preceding each row (0 before the
+    first). Segments are disjoint and in row order, so this is the prefix
+    sum of the gid steps placed at the segment starts: one O(segments)
+    scatter and one O(rows) cumsum, the sweep of ``_run_id_per_row``, in
+    place of a row-sized gather from the segments' gids. Integer, so
+    exact."""
+    prev = jnp.concatenate([jnp.zeros((1,), gid.dtype), gid[:-1]])
+    step = jnp.where(view.valid, gid - prev, 0)
+    delta = jnp.zeros((nrows + 1,), gid.dtype).at[view.starts].add(
+        step, mode="drop")
+    return jnp.cumsum(delta[:nrows])
 
 
 @jax.tree_util.register_dataclass
@@ -322,7 +334,7 @@ def groupby_aggregate(
         out = aggregate(view, gid, [(o, a, c) for o, a, c in specs],
                         num_groups_cap)
     else:
-        from repro.core.encodings import _run_id_per_row, decode_rle_coverage
+        from repro.core.encodings import decode_rle_coverage
         nrows = next(iter(cols.values())).nrows
         view = align_columns(pe, mask=mask)  # run-level segments
         gid, num_groups, rep = grouping(view, group_names, num_groups_cap,
@@ -330,11 +342,10 @@ def groupby_aggregate(
         run_specs = [(o, a, c) for o, a, c in specs
                      if c is None or c in view.values]
         out = aggregate(view, gid, run_specs, num_groups_cap)
-        # row -> segment -> group scatter for Plain aggregate columns
-        seg_of_row = _run_id_per_row(view.starts, view.n, nrows)
+        # row -> group scatter for Plain aggregate columns
         cov = decode_rle_coverage(view.starts, view.ends, view.n, nrows)
-        seg_c = jnp.clip(seg_of_row, 0, gid.shape[0] - 1)
-        gid_row = jnp.where(cov, gid[seg_c], num_groups_cap)  # drop slot
+        gid_row = jnp.where(cov, _gid_per_row(view, gid, nrows),
+                            num_groups_cap)  # drop slot
         f32 = jnp.float32
         counts = None
         for o, a, c in specs:
@@ -346,13 +357,11 @@ def groupby_aggregate(
             if a == "sum":
                 out[o] = ssum
             elif a == "min":
-                init = jnp.full((num_groups_cap,), jnp.inf, f32)
-                out[o] = init.at[gid_row].min(
-                    jnp.where(cov, v, jnp.inf), mode="drop")
+                out[o] = dispatch.segment_min(
+                    jnp.where(cov, v, jnp.inf), gid_row, num_groups_cap)
             elif a == "max":
-                init = jnp.full((num_groups_cap,), -jnp.inf, f32)
-                out[o] = init.at[gid_row].max(
-                    jnp.where(cov, v, -jnp.inf), mode="drop")
+                out[o] = dispatch.segment_max(
+                    jnp.where(cov, v, -jnp.inf), gid_row, num_groups_cap)
             elif a in ("avg", "var", "std"):
                 if counts is None:
                     counts = _segsum(view.lengths.astype(f32), gid,

@@ -110,9 +110,10 @@ def unique_bounded(values: jax.Array, valid: jax.Array, domain_size: int,
     The torch.unique/argsort in ``unique_with_inverse`` is the expensive
     part of every grouping (paper §7); when the key is a dictionary code or
     a centered narrow integer its domain is a small dense range known at
-    ingest, and unique reduces to a presence scatter + cumsum renumbering —
+    ingest, and unique reduces to a presence count + cumsum renumbering —
     O(n + domain) work, no O(n log n) sort (grouping directly on codes, the
-    Lin et al. companion-work trick).
+    Lin et al. companion-work trick). Over at most ``dispatch.G_DENSE``
+    codes the count is a dense compare; above, a scatter.
 
     ``valid`` masks slots out; out-of-domain values are dropped (callers
     guarantee in-domain via the ingest domain metadata, DESIGN.md §5).
@@ -122,7 +123,9 @@ def unique_bounded(values: jax.Array, valid: jax.Array, domain_size: int,
     """
     cap_groups = domain_size if cap_groups is None else cap_groups
     v = jnp.where(valid, values.astype(jnp.int32), domain_size)
-    counts = jnp.zeros((domain_size,), jnp.int32).at[v].add(1, mode="drop")
+    # dispatch-routed (DESIGN.md §5): over a small domain the count is a
+    # dense compare, not a row-sized scatter
+    counts = dispatch.segment_sum(jnp.ones_like(v), v, domain_size)
     present = counts > 0
     rank = (jnp.cumsum(present) - 1).astype(POS_DTYPE)
     num_groups = jnp.sum(present).astype(jnp.int32)

@@ -26,6 +26,12 @@ Policy resolution, in order:
      correctness harness, not a fast path), size thresholds below which
      the fused XLA op wins regardless of backend.
 
+Small-table segment reductions (``segment_sum`` over integers,
+``segment_min``, ``segment_max``) route on the table size and the
+backend: on the TPU, up to ``G_DENSE`` slots, they compare every row with
+every slot and reduce, where XLA's scatter would index the table row by
+row.
+
 The sort-free grouping knobs live here too (``enable_sort_free``,
 ``sort_free_max_domain``): scatter-grouping over a bounded code domain is
 the same class of decision — pick the implementation the encoding
@@ -35,6 +41,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import os
 from typing import Optional
 
@@ -53,6 +60,14 @@ from repro.kernels import ref as ref_mod
 _KERNEL_DTYPES = (jnp.int32, jnp.float32)
 
 MAX_MATMUL_SEGMENTS = 4096  # one-hot matmul: G must fit a VMEM block
+# Small-table segment reductions (DESIGN.md §5): up to this many slots, a
+# compare against every slot reduced along the rows beats XLA's row-sized
+# scatter, which the TPU runs as serialised per-row indexed access (7-9 ms
+# per 2^20 rows whatever the table size). The dense form costs about
+# 1.1 ms per 1024 slots there, so it wins up to about 6000 slots; 4096 is
+# the largest size the on-chip sweep measured (PERF.md §7). A TPU number:
+# other backends keep the scatter (``_dense_fuses``).
+G_DENSE = 4096
 # The counting bucketize compares every query with every boundary, so it
 # takes one boundary tile at most; longer lists keep XLA's searchsorted.
 COUNT_KERNEL_MAX_BOUNDARIES = B_TILE
@@ -329,16 +344,61 @@ def bucketize(boundaries: jax.Array, queries, right: bool = True) -> jax.Array:
     return jnp.searchsorted(boundaries, queries, side=side).astype(jnp.int32)
 
 
+def _dense_fuses() -> bool:
+    """Whether this backend runs the dense form fused. XLA:TPU keeps the
+    (G, rows) compare inside its reduce; XLA:CPU builds it in memory (17.7
+    GB of temporaries at G = 4096 and 2^20 rows) and scatters in O(rows),
+    so there the scatter stays (DESIGN.md §5)."""
+    return jax.default_backend() == "tpu"
+
+
+def _why_not_dense(num_segments: int) -> Optional[str]:
+    """None when a reduction into ``num_segments`` slots takes the dense
+    form; otherwise why it keeps XLA's scatter."""
+    if num_segments > G_DENSE:
+        return f"G={num_segments}>G_DENSE={G_DENSE}"
+    if not _dense_fuses():
+        return f"backend {jax.default_backend()} builds the dense compare"
+    return None
+
+
+# jitted so that an eager caller dispatches one program, not five ops;
+# inside a traced program they inline
+@functools.partial(jax.jit, static_argnums=(0, 3, 4))
+def _dense_segment_reduce(reduce: str, values: jax.Array,
+                          segment_ids: jax.Array, num_segments: int,
+                          identity) -> jax.Array:
+    """``out[g] = reduce(values[i] for i with segment_ids[i] == g)``, as a
+    broadcast compare against ``iota(G)`` reduced along the rows. The
+    (G, rows) operand lives only inside XLA's reduce fusion: rows sit on
+    the lanes, so G rides the sublanes and nothing row-sized is indexed."""
+    slots = jnp.arange(num_segments, dtype=segment_ids.dtype)[:, None]
+    hit = jnp.where(segment_ids[None, :] == slots, values[None, :],
+                    jnp.asarray(identity, values.dtype))
+    if reduce == "sum":
+        return jnp.sum(hit, axis=1, dtype=values.dtype)
+    fn = jnp.min if reduce == "min" else jnp.max
+    return fn(hit, axis=1, initial=identity)
+
+
 def segment_sum(values: jax.Array, segment_ids: jax.Array,
                 num_segments: int) -> jax.Array:
     """Segment sum; out-of-range ids (capacity padding) contribute 0.
 
-    MXU one-hot matmul when the policy allows and the group count fits a
-    VMEM block; XLA scatter-add otherwise. Only float32 routes to the
-    kernel (its accumulator is float32; integer callers — COUNT — keep
-    exact scatter arithmetic).
+    Integer values over at most ``G_DENSE`` groups, on the TPU: the dense
+    reduction (exact, like the scatter it replaces). Float32: the MXU
+    one-hot matmul when the policy allows and the group count fits a VMEM
+    block (its accumulator is float32, so integers never go there).
+    Everything else: XLA scatter-add, whose summation order float callers
+    keep.
     """
     pol = policy()
+    integer = jnp.issubdtype(values.dtype, jnp.integer)
+    why_not = _why_not_dense(num_segments)
+    if integer and why_not is None:
+        _route("segment_sum", "dense", f"G={num_segments}<=G_DENSE={G_DENSE}")
+        return _dense_segment_reduce("sum", values, segment_ids,
+                                     num_segments, 0)
     if (pol.pallas_enabled() and values.dtype == jnp.float32
             and 0 < num_segments <= pol.segment_sum_max_groups
             and values.shape[0] > 0):
@@ -348,13 +408,50 @@ def segment_sum(values: jax.Array, segment_ids: jax.Array,
         return segment_sum_kernel(values, segment_ids, num_segments,
                                   interpret=pol.interpret_mode())
     _route("segment_sum", "xla_scatter",
-           "pallas off" if not pol.pallas_enabled()
-           else f"dtype {values.dtype} keeps exact scatter arithmetic"
-           if values.dtype != jnp.float32
+           f"{why_not}; {values.dtype} keeps exact scatter arithmetic"
+           if integer
+           else "pallas off" if not pol.pallas_enabled()
+           else f"dtype {values.dtype}" if values.dtype != jnp.float32
            else f"G={num_segments} outside "
            f"(0, segment_sum_max_groups={pol.segment_sum_max_groups}]")
     return jnp.zeros((num_segments,), values.dtype).at[segment_ids].add(
         values, mode="drop")
+
+
+def _extreme_identity(reduce: str, dtype):
+    if jnp.issubdtype(dtype, jnp.floating):
+        return jnp.inf if reduce == "min" else -jnp.inf
+    info = jnp.iinfo(dtype)
+    return info.max if reduce == "min" else info.min
+
+
+def _segment_extreme(reduce: str, values: jax.Array, segment_ids: jax.Array,
+                     num_segments: int) -> jax.Array:
+    identity = _extreme_identity(reduce, values.dtype)
+    why_not = _why_not_dense(num_segments)
+    if why_not is None:
+        _route(f"segment_{reduce}", "dense",
+               f"G={num_segments}<=G_DENSE={G_DENSE}")
+        return _dense_segment_reduce(reduce, values, segment_ids,
+                                     num_segments, identity)
+    _route(f"segment_{reduce}", "xla_scatter", why_not)
+    init = jnp.full((num_segments,), identity, values.dtype)
+    at = init.at[segment_ids]
+    return (at.min if reduce == "min" else at.max)(values, mode="drop")
+
+
+def segment_min(values: jax.Array, segment_ids: jax.Array,
+                num_segments: int) -> jax.Array:
+    """Segment minimum; empty groups and out-of-range ids give the dtype's
+    identity (+inf, or the integer maximum). Dense reduction over at most
+    ``G_DENSE`` groups on the TPU, XLA scatter-min otherwise; both exact."""
+    return _segment_extreme("min", values, segment_ids, num_segments)
+
+
+def segment_max(values: jax.Array, segment_ids: jax.Array,
+                num_segments: int) -> jax.Array:
+    """Segment maximum; the mirror of ``segment_min``."""
+    return _segment_extreme("max", values, segment_ids, num_segments)
 
 
 def topk(values: jax.Array, k: int):

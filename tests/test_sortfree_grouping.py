@@ -12,6 +12,7 @@ absent): the range_union int32-overflow fix and the k-way fused
 intersect's run-boundary preservation.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -320,3 +321,194 @@ def test_partitioned_sortfree_matches_argsort(rng):
         np.testing.assert_array_equal(r_fast.keys[k], r_sort.keys[k])
     for a in r_fast.aggs:
         np.testing.assert_allclose(r_fast.aggs[a], r_sort.aggs[a], rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# dense small-table reductions (dispatch.G_DENSE) against the scatters
+# they replace, and the hybrid path's gid_row sweep
+# ---------------------------------------------------------------------------
+
+
+def _routes_while(fn):
+    """(result, {route counter: times taken}) while ``fn`` traces."""
+    from repro.core import telemetry
+
+    before = telemetry.registry().counters()
+    with dispatch.overrides(enable_trace=True):
+        out = fn()
+    after = telemetry.registry().counters()
+    return out, {k: v - before.get(k, 0) for k, v in after.items()
+                 if k.startswith("route.") and v > before.get(k, 0)}
+
+
+def _scatter_reference(reduce, values, ids, g):
+    """The XLA scatter each dense reduction replaces."""
+    if reduce == "sum":
+        return jnp.zeros((g,), values.dtype).at[ids].add(values, mode="drop")
+    if jnp.issubdtype(values.dtype, jnp.floating):
+        ident = jnp.inf if reduce == "min" else -jnp.inf
+    else:
+        info = jnp.iinfo(values.dtype)
+        ident = info.max if reduce == "min" else info.min
+    at = jnp.full((g,), ident, values.dtype).at[ids]
+    return (at.min if reduce == "min" else at.max)(values, mode="drop")
+
+
+GD = dispatch.G_DENSE
+
+
+@pytest.fixture
+def tpu_forms(monkeypatch):
+    """Route as on the TPU, where XLA fuses the dense form; this backend
+    runs the same jnp program, at test sizes."""
+    monkeypatch.setattr(dispatch, "_dense_fuses", lambda: True)
+
+
+@pytest.mark.parametrize("reduce", ["sum", "min", "max"])
+def test_backend_without_fused_dense_form_keeps_scatter(rng, reduce):
+    """XLA:CPU builds the (G, rows) compare in memory, so off the TPU a
+    small table keeps the scatter, with the same answer."""
+    path = "dense" if jax.default_backend() == "tpu" else "xla_scatter"
+    ids = rng.integers(0, 9, 1500).astype(np.int32)
+    values = rng.integers(-1000, 1000, 1500).astype(np.int32)
+    fn = {"sum": dispatch.segment_sum, "min": dispatch.segment_min,
+          "max": dispatch.segment_max}[reduce]
+    got, routes = _routes_while(
+        lambda: fn(jnp.asarray(values), jnp.asarray(ids), 8))
+    want = _scatter_reference(reduce, jnp.asarray(values), jnp.asarray(ids), 8)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert routes == {f"route.segment_{reduce}.{path}": 1}, routes
+
+
+@pytest.mark.usefixtures("tpu_forms")
+@pytest.mark.parametrize("rows", [0, 1500])
+@pytest.mark.parametrize("g", [1, GD, GD + 1])
+@pytest.mark.parametrize("reduce,dtype", [
+    ("sum", np.int32), ("min", np.int32), ("max", np.int32),
+    ("min", np.float32), ("max", np.float32)])
+def test_dense_segment_reduce_matches_scatter(rng, reduce, dtype, g, rows):
+    """int32 sums (COUNT, the presence count), int32 mins (``rep``) and
+    float/int min and max: the dense form is the scatter bit for bit, with
+    padding (id == G) and ids past it dropped; the route counter says which
+    form ran, on both sides of G_DENSE."""
+    ids = rng.integers(0, g + 3, rows).astype(np.int32)
+    if dtype == np.int32:
+        values = rng.integers(-1000, 1000, rows).astype(np.int32)
+    else:
+        values = rng.standard_normal(rows).astype(np.float32)
+    fn = {"sum": dispatch.segment_sum, "min": dispatch.segment_min,
+          "max": dispatch.segment_max}[reduce]
+    got, routes = _routes_while(
+        lambda: fn(jnp.asarray(values), jnp.asarray(ids), g))
+    want = _scatter_reference(reduce, jnp.asarray(values), jnp.asarray(ids), g)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    path = "dense" if g <= GD else "xla_scatter"
+    assert routes == {f"route.segment_{reduce}.{path}": 1}, routes
+
+
+@pytest.mark.usefixtures("tpu_forms")
+@pytest.mark.parametrize("domain", [1, GD, GD + 1])
+def test_unique_bounded_presence_count_both_forms(rng, domain):
+    """The presence count of ``unique_bounded`` agrees with the argsort
+    unique on both sides of G_DENSE, padding slots and empty input
+    included."""
+    for n in (0, 3000):
+        a = rng.integers(0, domain, n).astype(np.int32)
+        valid = rng.random(n) > 0.2
+        (u, inv, ng), routes = _routes_while(lambda: P.unique_bounded(
+            jnp.asarray(a), jnp.asarray(valid), domain, cap_groups=domain))
+        want = np.unique(a[valid])
+        assert int(ng) == len(want)
+        np.testing.assert_array_equal(np.asarray(u)[:len(want)], want)
+        np.testing.assert_array_equal(np.asarray(inv)[valid],
+                                      np.searchsorted(want, a[valid]))
+        path = "dense" if domain <= GD else "xla_scatter"
+        assert routes == {f"route.segment_sum.{path}": 1}, routes
+
+
+@pytest.mark.usefixtures("tpu_forms")
+@pytest.mark.parametrize("cap", [GD, GD + 1])
+def test_groupby_identical_across_dense_boundary(rng, cap):
+    """A whole group-by (keys from ``rep``, COUNT, min, max) with the group
+    table on either side of G_DENSE equals the numpy oracle."""
+    n = 3000
+    keys = rng.integers(0, 300, n).astype(np.int32)
+    vals = rng.standard_normal(n).astype(np.float32)
+    sel = rng.random(n) > 0.3
+    cols = {"k": E.make_plain(keys), "v": E.make_plain(vals)}
+    specs = [("c", "count", None), ("mn", "min", "v"), ("mx", "max", "v")]
+    r, routes = _routes_while(lambda: G.groupby_aggregate(
+        cols, ["k"], specs, num_groups_cap=cap, mask=E.make_plain_mask(sel),
+        key_domains={"k": compress.column_domain(keys)}))
+    path = "dense" if cap <= GD else "xla_scatter"
+    assert {f"route.segment_min.{path}", f"route.segment_max.{path}",
+            f"route.segment_sum.{path}"} <= set(routes), routes
+    uk = np.unique(keys[sel])
+    ng = int(r.num_groups)
+    assert ng == len(uk)
+    np.testing.assert_array_equal(np.asarray(r.keys["k"])[:ng], uk)
+    ks, vs = keys[sel], vals[sel]
+    np.testing.assert_array_equal(np.asarray(r.aggs["c"])[:ng],
+                                  [np.sum(ks == k) for k in uk])
+    np.testing.assert_array_equal(np.asarray(r.aggs["mn"])[:ng],
+                                  [vs[ks == k].min() for k in uk])
+    np.testing.assert_array_equal(np.asarray(r.aggs["mx"])[:ng],
+                                  [vs[ks == k].max() for k in uk])
+
+
+def _gid_per_row_by_gather(view, gid, nrows):
+    """The row -> segment -> group gather the sweep replaced."""
+    seg = E._run_id_per_row(view.starts, view.n, nrows)
+    return gid[jnp.clip(seg, 0, gid.shape[0] - 1)]
+
+
+def _gapped_rle(vals, keep):
+    """RLE column of ``vals`` whose runs cover only the rows in ``keep``."""
+    v, s, e = [], [], []
+    i, n = 0, len(vals)
+    while i < n:
+        if not keep[i]:
+            i += 1
+            continue
+        j = i
+        while j + 1 < n and keep[j + 1] and vals[j + 1] == vals[i]:
+            j += 1
+        v.append(vals[i])
+        s.append(i)
+        e.append(j)
+        i = j + 1
+    return E.make_rle(np.asarray(v, vals.dtype), s, e, n,
+                      capacity=len(v) + 4)
+
+
+@pytest.mark.parametrize("menc", [None, "rle", "index"])
+@pytest.mark.parametrize("kenc", ["rle", "gapped_rle", "index"])
+def test_hybrid_gid_row_sweep_identical_to_gather(rng, monkeypatch, kenc,
+                                                  menc):
+    """Hybrid path (RLE or Index keys, Plain aggregates, gaps in keys and
+    mask): the GroupByResult with the gid_row sweep is bit-identical to
+    the one with the old row-sized gather."""
+    n = 700
+    keys = np.sort(rng.integers(0, 6, n)).astype(np.int32)
+    vals = rng.standard_normal(n).astype(np.float32)
+    keep = rng.random(n) > 0.15
+    if kenc == "rle":
+        kcol = make_rle_col(keys)
+    elif kenc == "gapped_rle":
+        kcol = _gapped_rle(keys, keep)
+    else:
+        pos = np.nonzero(keep)[0]
+        kcol = E.make_index(keys[pos], pos, nrows=n, capacity=len(pos) + 4)
+    cols = {"k": kcol, "v": E.make_plain(vals)}
+    mask = MASK_ENCODERS[menc](rng.random(n) > 0.3) if menc else None
+    specs = SPECS + [("sd", "std", "v"), ("mn", "min", "v")]
+
+    def run():
+        return G.groupby_aggregate(
+            cols, ["k"], specs, num_groups_cap=8, mask=mask,
+            key_domains={"k": compress.column_domain(keys)})
+
+    swept = run()
+    monkeypatch.setattr(G, "_gid_per_row", _gid_per_row_by_gather)
+    _assert_identical(swept, run())

@@ -13,6 +13,7 @@ one process at a time may load the TPU library, and under pytest-xdist
 only the worker that runs this file may try.
 """
 import os
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -70,7 +71,10 @@ def _shapes(tree, sharding):
 
 
 def _compile_text(fn, args, sharding) -> str:
-    with dispatch.overrides(use_pallas=True, interpret=False):
+    # trace as on the chip: Pallas on, and the dense small-table reductions
+    # the TPU backend takes
+    with dispatch.overrides(use_pallas=True, interpret=False), \
+            mock.patch.object(dispatch, "_dense_fuses", lambda: True):
         return jax.jit(fn).lower(*_shapes(args, sharding)).compile().as_text()
 
 
@@ -185,3 +189,96 @@ def test_production_q1_partition_program_compiles(one_chip):
     assert {"route.segment_sum.kernel", "route.bucketize.count_kernel",
             "route.unpack.kernel"} <= routes, routes
     assert "tpu_custom_call" in text
+
+
+def _row_indexed_ops(text: str, rows: int):
+    """The scatters and gathers of a compiled HLO module whose index operand
+    (operand 1) has ``rows`` in its shape, as ``(op, line)`` pairs."""
+    import re
+
+    define = re.compile(r"^\s*(?:ROOT )?%?(\S+) = (\S+) ([\w\-]+)\((.*)")
+    found, shapes = [], {}
+    for line in text.splitlines():
+        if line and not line.startswith(" ") and line.rstrip().endswith("{"):
+            shapes = {}  # a new computation: its own operand names
+            continue
+        m = define.match(line)
+        if not m:
+            continue
+        name, shape, op, rest = m.groups()
+        shapes[name] = shape
+        if op in ("scatter", "gather"):
+            args = [a.strip().lstrip("%")
+                    for a in rest.split(")", 1)[0].split(",")]
+            dims = re.findall(r"\d+", shapes.get(args[1], "").split("{")[0])
+            if str(rows) in dims:
+                found.append((op, line.strip()[:200]))
+    return found
+
+
+def _q1_partition(form: str, rows: int):
+    """A TPC-H lineitem partition of ``rows`` rows, sorted on l_shipdate.
+    ``row_level``: l_returnflag random R/A (Plain), as before the spec's
+    CURRENTDATE; ``hybrid``: l_returnflag and l_linestatus in long runs
+    (RLE), as after it."""
+    rng = np.random.default_rng(14)
+    ship = np.sort(rng.integers(9000, 9090, rows)).astype(np.int32)
+    if form == "row_level":
+        flag = np.where(rng.integers(0, 2, rows) == 0, "R", "A")
+        status = np.full(rows, "F")
+    else:
+        flag = np.where(np.arange(rows) < rows // 8, "A", "N")
+        status = np.where(ship > 9030, "O", "F")
+    return {
+        "l_shipdate": ship,
+        "l_returnflag": flag,
+        "l_linestatus": status,
+        "l_quantity": rng.integers(1, 51, rows).astype(np.int32),
+        "l_extendedprice": (rng.random(rows) * 1e5).astype(np.float32),
+        "l_discount": (rng.integers(0, 11, rows) / 100).astype(np.float32),
+        "l_tax": (rng.integers(0, 9, rows) / 100).astype(np.float32),
+    }
+
+
+@pytest.mark.parametrize("form", ["row_level", "hybrid"])
+def test_q1_partition_program_has_no_row_sized_scatter_or_gather(one_chip,
+                                                                form):
+    """TPC-H Q1's group-by over one packed 2^20-row partition, in its
+    row-level form (a Plain key) and its hybrid form (RLE keys, Plain
+    aggregates): the TPU program indexes no small table row by row. The
+    presence count, the rank lookup, ``rep`` and COUNT reduce densely;
+    the hybrid path's row -> group ids come from a sweep."""
+    from repro.core.arithmetic import binary_op
+    from repro.core.encodings import PlainColumn, RLEColumn
+
+    rows = 1 << 20
+    table = PartitionedTable.from_arrays(
+        _q1_partition(form, rows), cfg=compress.CompressionConfig(),
+        partition_rows=rows, pack=True)
+    cols = table.partitions[0].table.columns
+    key_enc = (PlainColumn if form == "row_level" else RLEColumn)
+    assert isinstance(cols["l_returnflag"], key_enc)
+    assert isinstance(cols["l_linestatus"], RLEColumn)
+    q = (PartitionedQuery(table)
+         .filter(col("l_shipdate") <= 9060)
+         .map("disc_price", lambda env: binary_op(
+             env["l_extendedprice"], env["l_discount"],
+             lambda e, d: e * (1 - d)))
+         .map("charge", lambda env: binary_op(
+             env["disc_price"], env["l_tax"], lambda p, t: p * (1 + t)))
+         .groupby(["l_returnflag", "l_linestatus"],
+                  {"sum_qty": ("sum", "l_quantity"),
+                   "sum_base_price": ("sum", "l_extendedprice"),
+                   "sum_disc_price": ("sum", "disc_price"),
+                   "sum_charge": ("sum", "charge"),
+                   "avg_qty": ("avg", "l_quantity"),
+                   "avg_price": ("avg", "l_extendedprice"),
+                   "avg_disc": ("avg", "l_discount"),
+                   "count_order": ("count", None)}, num_groups_cap=8))
+    args = (cols, tuple(q._prepare_inputs()), np.int32(rows))
+    text, routes = _routes_while(lambda: _compile_text(
+        base_masked_program(q.build(partial=True)), args, one_chip))
+    assert {"route.segment_sum.dense", "route.segment_min.dense",
+            "route.segment_sum.kernel"} <= routes, routes
+    assert "tpu_custom_call" in text
+    assert _row_indexed_ops(text, rows) == []
