@@ -174,22 +174,44 @@ def gather_rows(col, rows: jax.Array, valid: jax.Array):
 
 
 # ---------------------------------------------------------------------------
-# PK-FK star-schema join (paper §8.1 + Table 6): probe a sorted unique-key
-# dimension side at ENCODING granularity — one binary search per run/point/
-# row, never a run expansion (a PK match is at most one dimension row, so
-# the Join Index degenerates to a gather and stays in the fact encoding).
+# PK-FK star-schema join (paper §8.1 + Table 6): probe a unique-key
+# dimension side at ENCODING granularity — one lookup per run/point/row,
+# never a run expansion (a PK match is at most one dimension row, so the
+# Join Index degenerates to a gather and stays in the fact encoding).
 # ---------------------------------------------------------------------------
 
+# Largest fact-FK domain, in slots, the direct-address probe maps. The map
+# is built on the host, shipped and held on the device once per execution:
+# 2^22 int32 slots are 16 MiB, less than one streamed 2^20-row partition
+# of a wide fact table ships (SSB lineorder: about 29 MiB packed), so the
+# map never costs more transfer or HBM than the partitions it serves.
+# Wider domains keep the sorted build side and its binary search.
+DIRECT_MAP_MAX_SLOTS = 1 << 22
 
-def pk_fk_join(fact_key_col, dim_keys: jax.Array, n_dim: jax.Array,
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class DirectMap:
+    """Direct-address build side of a PK-FK join over the fact FK's
+    ingest domain ``[lo, hi]``: ``rows[k - lo]`` is the dimension row
+    (index into the payload arrays) of the surviving key ``k``, -1 where no
+    row survives. One row-sized gather replaces the binary search."""
+
+    rows: jax.Array  # (hi - lo + 1,) int32
+    lo: jax.Array  # int32 scalar: the domain's first key
+    hi: jax.Array  # int32 scalar: the domain's last key
+
+
+def pk_fk_join(fact_key_col, dim_keys, n_dim: jax.Array,
                payloads: dict, fill=0):
-    """Sort-merge PK-FK probe: returns ``(mask, gathered)``.
+    """PK-FK probe: returns ``(mask, gathered)``.
 
-    ``dim_keys`` is the build side — surviving dimension PK values in the
-    fact key's value space, sorted, sentinel-padded past ``n_dim`` (the
-    plan layer prepares it once per query from ingest-recorded sort order).
+    ``dim_keys`` is the build side the plan layer prepares once per query:
+    a ``DirectMap`` over the fact FK's domain (one gather per probe), or
+    the surviving dimension PK values in the fact key's value space,
+    sorted, sentinel-padded past ``n_dim`` (a binary search per probe).
     ``payloads`` maps names to dense per-dimension-row value arrays in the
-    same order.
+    build side's row order.
 
     ``mask`` is the inner-join membership mask in the fact column's own
     encoding (whole RLE runs pass/fail together — §8.1's "treat each run
@@ -204,6 +226,14 @@ def pk_fk_join(fact_key_col, dim_keys: jax.Array, n_dim: jax.Array,
                                    nrows=fact_key_col.nrows)
 
     def probe(keys, kvalid):
+        if isinstance(dim_keys, DirectMap):
+            # keys outside [lo, hi] never match; the offset is taken only
+            # inside it, where it cannot wrap
+            k = unpack_values(keys)
+            inside = (k >= dim_keys.lo) & (k <= dim_keys.hi)
+            off = jnp.where(inside, k - dim_keys.lo, 0)
+            row = dispatch.direct_lookup(dim_keys.rows, off)
+            return jnp.maximum(row, 0), kvalid & inside & (row >= 0)
         # packed fact keys route to the fused unpack->bisect kernel; the
         # membership equality reads the (lazily) unpacked codes, which XLA
         # CSEs with any other consumer of the same extraction

@@ -831,6 +831,7 @@ class PartitionedQuery(Query):
             "executed": len(todo),
             "skipped": len(ptable.partitions) - len(todo),
             "pruned_by": pruned_by,
+            "join_prep_ms": round(self.join_prep_ms, 3),
         }
         depth, stats = self._depth_and_stats(ptable)
         # trace spans name partitions by their ingest index, matching

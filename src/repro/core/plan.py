@@ -839,9 +839,12 @@ class Query:
     def _prepare_inputs(self):
         """Eager host-side preparation, one entry per semi-join / join op in
         (reordered) pipeline order — the program pops them positionally, so
-        this reorders FIRST, exactly as ``build`` will."""
+        this reorders FIRST, exactly as ``build`` will. Each join side is
+        a ``join_side`` span; their milliseconds sum into
+        ``join_prep_ms``."""
         self._reorder_semijoins()
         prepared = []
+        self.join_prep_ms = 0.0
         for op in self.ops:
             if isinstance(op, _SemiJoinOp):
                 keys = np.unique(op.keys)
@@ -849,10 +852,13 @@ class Query:
                     keys, np.full((1,), _sentinel_for(keys.dtype), keys.dtype)]))
                 prepared.append((arr, jnp.asarray(len(keys), jnp.int32)))
             elif isinstance(op, _JoinOp):
-                prepared.append(self._prepare_join_side(op))
+                with telemetry.timed("join_side", (self, "join_prep_ms"),
+                                     qid=self.qid, dim=op.on, fk=op.fk,
+                                     rows=op.dim.nrows) as sp:
+                    prepared.append(self._prepare_join_side(op, sp))
         return prepared
 
-    def _prepare_join_side(self, op: _JoinOp):
+    def _prepare_join_side(self, op: _JoinOp, sp=telemetry.NULL_SPAN):
         """Build the dimension side of a PK-FK join, ONCE per execution:
 
           1. evaluate ``where`` eagerly on the (small) dimension table,
@@ -861,11 +867,18 @@ class Query:
              when the dimension is stored key-ordered),
           3. translate surviving PK values into the fact FK's stored value
              space (dictionary codes when the FK is dictionary-encoded),
-          4. pad to a pow2 capacity with sentinel keys so re-preparation
-             with a different surviving-key count reuses the jit cache.
+          4. pad payloads to a pow2 capacity so re-preparation with a
+             different surviving-key count reuses the jit cache,
+          5. when the fact FK has an ingest domain of at most
+             ``join.DIRECT_MAP_MAX_SLOTS`` slots, map it directly (a
+             ``join.DirectMap``: key - lo -> payload row, -1 where no row
+             survives, whose shape is the domain's); otherwise pad the
+             sorted keys with sentinels for the binary search.
 
-        Returns ``(keys, n, payloads)`` device arrays and records the host
-        key set on the op for FK zone-map partition pruning.
+        Returns ``(build side, n, payloads)`` device arrays and records the
+        host key set on the op for FK zone-map partition pruning. Counts
+        the route (``join.probe.direct`` / ``join.probe.sorted``) and the
+        map's bytes (``join.map_bytes``), and sets them on ``sp``.
         """
         dim = op.dim
         keep = None
@@ -924,18 +937,39 @@ class Query:
         op.host_keys = keys
         n = len(keys)
         cap = compress.next_pow2(n + 1, 8)
-        sentinel = _sentinel_for(keys.dtype)
-        keys_p = np.concatenate(
-            [keys, np.full((cap - n,), sentinel, keys.dtype)])
-        pay_p = {c: np.concatenate([v, np.zeros((cap - n,), v.dtype)])
+        pay_p = {c: jnp.asarray(np.concatenate(
+                     [v, np.zeros((cap - n,), v.dtype)]))
                  for c, v in payloads.items()}
-        return (jnp.asarray(keys_p), jnp.asarray(n, jnp.int32),
-                {c: jnp.asarray(v) for c, v in pay_p.items()})
+        # the probe reads the LIVE value bound to the FK's name at this op:
+        # an earlier map or join may have rebound it past the ingest domain
+        _, live = _live_domains_at(self.ops, self.table, lambda o: o is op)
+        dom = (live or {}).get(op.fk)
+        if (dom is not None and keys.dtype == np.int32
+                and dom[1] <= join_mod.DIRECT_MAP_MAX_SLOTS):
+            lo, size = int(dom[0]), int(dom[1])
+            off = keys.astype(np.int64) - lo
+            inside = (off >= 0) & (off < size)  # others match no fact key
+            rows = np.full((size,), -1, np.int32)
+            rows[off[inside]] = np.flatnonzero(inside)
+            side = join_mod.DirectMap(rows=jnp.asarray(rows),
+                                      lo=jnp.asarray(lo, jnp.int32),
+                                      hi=jnp.asarray(lo + size - 1, jnp.int32))
+            route, slots = "direct", size
+            telemetry.add_counter("join.map_bytes", rows.nbytes)
+        else:
+            keys_p = np.concatenate(
+                [keys, np.full((cap - n,), _sentinel_for(keys.dtype),
+                               keys.dtype)])
+            side = jnp.asarray(keys_p)
+            route, slots = "sorted", cap
+        telemetry.add_counter(f"join.probe.{route}")
+        sp.set(keys=n, route=route, slots=slots)
+        return side, jnp.asarray(n, jnp.int32), pay_p
 
 
-def _live_domains_at(ops, table, stop_type):
-    """Walk ``ops`` maintaining live ingest domains up to the first
-    ``stop_type`` op; returns (op, live domains) or (None, None).
+def _live_domains_at(ops, table, stop):
+    """Walk ``ops`` maintaining live ingest domains up to the first op for
+    which ``stop(op)`` holds; returns (op, live domains) or (None, None).
 
     Walked in pipeline order, like zone maps in partition_can_match: a
     ``map`` rebinding a column name invalidates its domain (the recorded
@@ -945,6 +979,8 @@ def _live_domains_at(ops, table, stop_type):
     domain (global dictionary code space / integer bounds)."""
     live = dict(getattr(table, "domains", None) or {})
     for op in ops:
+        if stop(op):
+            return op, live
         if isinstance(op, _MapOp):
             live.pop(op.out, None)
         elif isinstance(op, _JoinOp):
@@ -953,8 +989,6 @@ def _live_domains_at(ops, table, stop_type):
                 dom = (getattr(op.dim, "domains", None) or {}).get(c)
                 if dom is not None:
                     live[out] = dom
-        elif isinstance(op, stop_type):
-            return op, live
     return None, None
 
 
@@ -962,7 +996,8 @@ def _groupby_key_domains(ops, table):
     """Bounded-domain metadata (name -> (lo, size)) for the terminal
     group-by's key columns, from ``table.domains`` (ingest-recorded) —
     the sort-free grouping contract (see ``_live_domains_at``)."""
-    op, live = _live_domains_at(ops, table, _GroupByOp)
+    op, live = _live_domains_at(
+        ops, table, lambda o: isinstance(o, _GroupByOp))
     if op is None:
         return None
     doms = {g: live[g] for g in op.group if g in live}
@@ -973,7 +1008,8 @@ def _order_key_domains(ops, table):
     """Bounded-domain metadata for a row-terminal order_by's keys — the
     histogram-rank path's contract (order.top_k_rows), with the same
     pipeline-order invalidation as the group-by domains."""
-    op, live = _live_domains_at(ops, table, _OrderByOp)
+    op, live = _live_domains_at(
+        ops, table, lambda o: isinstance(o, _OrderByOp))
     if op is None or any(isinstance(o, _GroupByOp) for o in ops):
         return None
     doms = {b: live[b] for b in op.by if b in live}

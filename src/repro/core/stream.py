@@ -138,28 +138,6 @@ def emit_stage(tel, stats: StreamStats, field: Optional[str], name: str,
         tel.record(name, t0, t1 - t0, track, qid=stats.qid, **attrs)
 
 
-class _Clock:
-    """Untraced stage region: adds its wall milliseconds to one
-    ``StreamStats`` field. One per field per executor pass, reused by every
-    partition, so the untraced path allocates no span and takes no lock."""
-
-    __slots__ = ("stats", "field", "t0")
-
-    def __init__(self, stats: StreamStats, field: str):
-        self.stats = stats
-        self.field = field
-
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        st = self.stats
-        setattr(st, self.field, getattr(st, self.field)
-                + (time.perf_counter() - self.t0) * 1e3)
-        return False
-
-
 _STAGE_FIELDS = ("h2d_ms", "compute_ms", "merge_ms", "d2h_ms")
 
 
@@ -170,12 +148,13 @@ class _Stages:
     telemetry span (ring event + ``repro:<name>`` profiler annotation,
     child of the span open on the thread) that adds its milliseconds to
     ``stats.<field>`` from its own timestamp pair; untraced, a reused
-    ``_Clock`` adds the same milliseconds and nothing else."""
+    ``telemetry.SinkClock`` adds the same milliseconds and nothing else."""
 
     def __init__(self, tel, stats: StreamStats):
         self.tel = tel
         self.stats = stats
-        self.clocks = ({f: _Clock(stats, f) for f in _STAGE_FIELDS}
+        self.clocks = ({f: telemetry.SinkClock(stats, f)
+                        for f in _STAGE_FIELDS}
                        if tel is None else None)
         # the transfer thread's spans name the query's root explicitly
         self.root = telemetry.current_span_id() if tel is not None else None

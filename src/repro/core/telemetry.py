@@ -358,6 +358,40 @@ def span(name: str, track: str = "main", **attrs):
     return _Span(_REGISTRY, name, track, attrs)
 
 
+class SinkClock:
+    """Untraced stage region: adds its wall milliseconds to ``obj.field``
+    and records nothing. Reusable: the streamed executor keeps one per
+    stats field for a whole pass, so the untraced path allocates no span
+    and takes no lock."""
+
+    __slots__ = ("obj", "field", "t0")
+
+    def __init__(self, obj, field: str):
+        self.obj = obj
+        self.field = field
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        setattr(self.obj, self.field, getattr(self.obj, self.field)
+                + (time.perf_counter() - self.t0) * 1e3)
+        return False
+
+
+def timed(name: str, sink, track: str = "main", **attrs):
+    """``span`` whose milliseconds are also added to ``sink=(obj, field)``,
+    traced or not: traced from the span's own timestamp pair, untraced by
+    a bare clock."""
+    if not enabled():
+        return SinkClock(*sink)
+    return _Span(_REGISTRY, name, track, attrs, sink=sink)
+
+
 def instant(name: str, track: str = "main", **attrs) -> None:
     if enabled():
         _REGISTRY.instant(name, track, **attrs)

@@ -312,6 +312,16 @@ def unpack(packed) -> jax.Array:
     return ref_mod.ref_unpack(words, packed.bit_width, packed.offset, n)
 
 
+def direct_lookup(table: jax.Array, index: jax.Array) -> jax.Array:
+    """``table[index]``, the direct-address PK-FK probe (core/join.py): one
+    row-sized gather from a map over the fact key's domain, in place of a
+    binary search over the sorted build side. XLA's gather on every
+    backend: a Pallas twin would rest on the 1-D gather Mosaic refuses
+    (``OFF_TPU_ROUTE``)."""
+    _route("join_probe", "direct", f"map slots={table.shape[0]}")
+    return table[index]
+
+
 def bucketize(boundaries: jax.Array, queries, right: bool = True) -> jax.Array:
     """torch.bucketize == searchsorted (right=True -> side='right').
 

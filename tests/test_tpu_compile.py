@@ -13,6 +13,7 @@ one process at a time may load the TPU library, and under pytest-xdist
 only the worker that runs this file may try.
 """
 import os
+import re
 from unittest import mock
 
 import numpy as np
@@ -194,8 +195,6 @@ def test_production_q1_partition_program_compiles(one_chip):
 def _row_indexed_ops(text: str, rows: int):
     """The scatters and gathers of a compiled HLO module whose index operand
     (operand 1) has ``rows`` in its shape, as ``(op, line)`` pairs."""
-    import re
-
     define = re.compile(r"^\s*(?:ROOT )?%?(\S+) = (\S+) ([\w\-]+)\((.*)")
     found, shapes = [], {}
     for line in text.splitlines():
@@ -282,3 +281,63 @@ def test_q1_partition_program_has_no_row_sized_scatter_or_gather(one_chip,
             "route.segment_sum.kernel"} <= routes, routes
     assert "tpu_custom_call" in text
     assert _row_indexed_ops(text, rows) == []
+
+
+def _while_ops(text: str) -> int:
+    return len(re.findall(r"\swhile\(", text))
+
+
+def _ssb_join_partition(case: str, rows: int):
+    """One packed 2^20-row lineorder partition and the dimension it probes:
+    ``custkey``, an RLE ``lo_custkey`` (an order's lines share it) against
+    the 60,000-row customer side; ``partkey``, a Plain ``lo_partkey``
+    against the 400,000-row part side."""
+    rng = np.random.default_rng(15)
+    n_dim = 60_000 if case == "custkey" else 400_000
+    if case == "custkey":
+        fk = np.repeat(rng.integers(1, n_dim + 1, rows), 4)[:rows]
+    else:
+        fk = rng.integers(1, n_dim + 1, rows)
+    fact = {"fk": fk.astype(np.int32),
+            "v": rng.integers(0, 10_000, rows).astype(np.int32)}
+    enc = {"fk": "rle" if case == "custkey" else "plain"}
+    table = PartitionedTable.from_arrays(fact, cfg=compress.CompressionConfig(),
+                                         partition_rows=rows, pack=True,
+                                         encodings=enc)
+    dim = Table.from_arrays({"pk": np.arange(1, n_dim + 1, dtype=np.int32),
+                             "g": rng.integers(0, 5, n_dim).astype(np.int32)})
+    return table, dim
+
+
+@pytest.mark.parametrize("case", ["custkey", "partkey"])
+def test_direct_join_probe_leaves_no_while_loop(one_chip, case, monkeypatch):
+    """A SUM under one PK-FK join, compiled for one partition on both
+    routes: the sorted build side's binary search is a ``while`` loop, the
+    direct map's probe one gather and no loop. The RLE key keeps two
+    loops of its own: the intersection of the join's run mask with the
+    one-run base mask (``primitives`` range intersection) searches the
+    base run's two bounds in the mask's run lists, one query each."""
+    from repro.core import join as join_mod
+    from repro.core.encodings import PlainColumn, RLEColumn
+
+    rows = 1 << 20
+    table, dim = _ssb_join_partition(case, rows)
+    cols = table.partitions[0].table.columns
+    assert isinstance(cols["fk"],
+                      RLEColumn if case == "custkey" else PlainColumn)
+    whiles = {}
+    for route, bound in (("direct", join_mod.DIRECT_MAP_MAX_SLOTS),
+                         ("sorted", 0)):
+        monkeypatch.setattr(join_mod, "DIRECT_MAP_MAX_SLOTS", bound)
+        q = (PartitionedQuery(table)
+             .join(dim, fk="fk", on="pk", cols=[], where=col("g") < 2)
+             .aggregate({"s": ("sum", "v")}))
+        args = (cols, tuple(q._prepare_inputs()), np.int32(rows))
+        assert isinstance(args[1][0][0], join_mod.DirectMap) == (
+            route == "direct")
+        text, routes = _routes_while(lambda: _compile_text(
+            base_masked_program(q.build(partial=True)), args, one_chip))
+        assert ("route.join_probe.direct" in routes) == (route == "direct")
+        whiles[route] = _while_ops(text)
+    assert whiles["direct"] == whiles["sorted"] - 1, whiles
+    assert whiles["direct"] == (2 if case == "custkey" else 0), whiles
